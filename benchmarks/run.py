@@ -54,6 +54,9 @@ def _run_inprocess(mod_name: str) -> None:
 
     import jax
 
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     # metadata rows for the coordinator's perf record — they describe
     # THIS worker (the coordinator stays jax-free by design, see header)
     print(f"_meta/backend,0,{jax.default_backend()}"

@@ -9,13 +9,26 @@ TPU adaptation of the paper's CSB-Engine (DESIGN.md §2):
 * The FPGA engine gathers input neurons by ColIdx through a buffer port and
   scatter-accumulates by RowIdx. TPUs have no cheap random access out of
   VMEM, so both indirections become **one-hot matmuls** that run on the
-  MXU: ``gather = X_blk @ C^T`` with ``C[l, :] = onehot(col_idx[l])`` and
-  ``scatter = Yk @ R`` with ``R[k, :] = onehot(row_idx[k])``.
+  MXU: ``gather = X_blk @ C`` with ``C[l, k] = (col_idx[k] == l)`` and
+  ``scatter = Yk @ R^T`` with ``R[j, k] = (row_idx[k] == j)``.
 * inner-block parallelism  -> the (TB, Pn) x (Pn, Pm) kernel matmul;
 * inter-block parallelism  -> the grid over block-rows x batch tiles, with
   the block-column dimension folded into a sequential accumulation axis
   (the standard TPU reduction-in-grid pattern);
 * the WeightBuffer         -> BlockSpec-staged VMEM tiles.
+
+Operand layout (Mosaic wants the last two dims of every block divisible
+by (8, 128) or equal to the array's own):
+
+* ``x`` goes in block-major as ``(Bc, B, bn)`` and the output comes out
+  as ``(Br, B, bm)``, so a tile's trailing dims are ``(batch_tile, bn)``
+  / ``(batch_tile, bm)`` and every block width (32, 64, 128, ...) is
+  legal; the wrapper transposes the activations around the call;
+* the survivor indices carry a unit axis, ``(Br, Bc, 1, P)``, so each
+  block's index vector is a full-width ``(1, P)`` row. The true kernel
+  dims ``m``/``n`` are folded into them before the call: pad lanes get
+  index -1, which no one-hot lane matches, so no count operand is
+  staged at all.
 
 Workload balance across grid cells is the *scheduler's* job
 (engine/schedule.py); this kernel executes whatever block layout it is
@@ -23,7 +36,8 @@ handed, masking pad lanes so padded FLOPs never corrupt results.
 
 Grid: ``(batch_tiles, Br, Bc/G)`` — the last axis accumulates into a
 VMEM scratch tile (minor-most, so the accumulator stays resident) and
-stores the output block once, on the final column step.
+stores the output block once, on the final column step. All three dots
+run at ``Precision.HIGHEST`` so the kernel is an fp32 matvec on the MXU.
 """
 from __future__ import annotations
 
@@ -35,47 +49,24 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.csb_format import PaddedCSB
-
-
-def _tpu_interpret_available() -> bool:
-    """Does this jax expose ``pltpu.force_tpu_interpret_mode``? (landed
-    after 0.4.37; the CI golden lane installs a jax that has it)."""
-    try:
-        from jax.experimental.pallas import tpu as pltpu
-    except ImportError:  # pragma: no cover
-        return False
-    return hasattr(pltpu, "force_tpu_interpret_mode")
-
-
-def force_tpu_interpret_requested() -> bool:
-    """The CI golden lane sets REPRO_FORCE_TPU_INTERPRET=1 so the
-    compiled-path branch below is exercised on CPU runners under
-    ``pltpu.force_tpu_interpret_mode`` (tests/conftest.py enters it)."""
-    return os.environ.get("REPRO_FORCE_TPU_INTERPRET", "0") not in ("", "0")
+_HI = jax.lax.Precision.HIGHEST
+_NT = (((1,), (1,)), ((), ()))          # contract both operands' dim 1
 
 
 def default_interpret() -> bool:
-    """Interpret-mode default by backend: real accelerators (TPU, GPU)
-    compile the kernel; CPU (CI, the container) has no Mosaic/Triton
-    target and interprets. The block-column reduction accumulates in a
-    kernel *scratch* buffer and stores ``o_ref`` exactly once per output
-    tile (no cross-step read-modify-write on the output ref), so the
-    kernel no longer depends on TPU's sequential-grid revisit semantics
-    and GPU no longer has to stay interpreted.
-
-    Under REPRO_FORCE_TPU_INTERPRET the TPU branch (interpret=False) is
-    taken on CPU too, relying on ``force_tpu_interpret_mode`` to emulate
-    the Mosaic lowering — the golden lane for the compiled path. On a
-    jax too old to have that context manager we stay interpreted rather
-    than fail to lower."""
-    if force_tpu_interpret_requested() and _tpu_interpret_available():
+    """Interpret-mode default by backend: the TPU compiles the kernel
+    with Mosaic; any other backend (the CPU of tests and CI) interprets.
+    The CI golden lane sets REPRO_FORCE_TPU_INTERPRET=1: the TPU branch
+    (interpret=False) is then taken on the CPU too, under
+    ``pltpu.force_tpu_interpret_mode`` (tests/conftest.py enters it),
+    which emulates the Mosaic lowering."""
+    if os.environ.get("REPRO_FORCE_TPU_INTERPRET", "0") not in ("", "0"):
         return False
-    return jax.default_backend() not in ("tpu", "gpu")
+    return jax.default_backend() != "tpu"
 
 
-def _kernel(x_ref, vals_ref, ridx_ref, cidx_ref, m_ref, n_ref, o_ref,
-            acc_ref, *, bm: int, bn: int, group: int):
+def _kernel(x_ref, vals_ref, ridx_ref, cidx_ref, o_ref, acc_ref, *,
+            group: int):
     """One grid step: TB batch rows x one block-row x G blocks.
 
     The block-column reduction (grid axis 2) accumulates into the VMEM
@@ -89,47 +80,43 @@ def _kernel(x_ref, vals_ref, ridx_ref, cidx_ref, m_ref, n_ref, o_ref,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    pm = vals_ref.shape[-2]
-    pn = vals_ref.shape[-1]
+    pm, pn = vals_ref.shape[-2:]
+    bn = x_ref.shape[-1]
+    bm = o_ref.shape[-1]
     acc = acc_ref[...]
     for g in range(group):
         # ---- gather input neurons by ColIdx (one-hot matmul on MXU) ----
-        xs = x_ref[:, g * bn:(g + 1) * bn].astype(jnp.float32)   # (TB, bn)
-        cidx = cidx_ref[0, g]                                    # (Pn,)
-        n_valid = n_ref[0, g]
-        lane = jax.lax.broadcasted_iota(jnp.int32, (pn, bn), 1)
+        xs = x_ref[g].astype(jnp.float32)                        # (TB, bn)
         coh = jnp.where(
-            (cidx[:, None] == lane)
-            & (jax.lax.broadcasted_iota(jnp.int32, (pn, bn), 0)
-               < n_valid),
-            1.0, 0.0)                                            # (Pn, bn)
-        xg = jax.lax.dot_general(
-            xs, coh, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)                  # (TB, Pn)
+            jax.lax.broadcasted_iota(jnp.int32, (bn, pn), 0)
+            == cidx_ref[0, g], 1.0, 0.0)                         # (bn, Pn)
+        xg = jnp.dot(xs, coh, precision=_HI,
+                     preferred_element_type=jnp.float32)         # (TB, Pn)
 
         # ---- dense kernel-matrix MVM (the paper's inner-block work) ----
         kmat = vals_ref[0, g].astype(jnp.float32)                # (Pm, Pn)
         yk = jax.lax.dot_general(
-            xg, kmat, (((1,), (1,)), ((), ())),
+            xg, kmat, _NT, precision=_HI,
             preferred_element_type=jnp.float32)                  # (TB, Pm)
 
         # ---- scatter to output rows by RowIdx --------------------------
-        ridx = ridx_ref[0, g]                                    # (Pm,)
-        m_valid = m_ref[0, g]
-        rlane = jax.lax.broadcasted_iota(jnp.int32, (pm, bm), 1)
         roh = jnp.where(
-            (ridx[:, None] == rlane)
-            & (jax.lax.broadcasted_iota(jnp.int32, (pm, bm), 0)
-               < m_valid),
-            1.0, 0.0)                                            # (Pm, bm)
+            jax.lax.broadcasted_iota(jnp.int32, (bm, pm), 0)
+            == ridx_ref[0, g], 1.0, 0.0)                         # (bm, Pm)
         acc = acc + jax.lax.dot_general(
-            yk, roh, (((1,), (0,)), ((), ())),
+            yk, roh, _NT, precision=_HI,
             preferred_element_type=jnp.float32)                  # (TB, bm)
     acc_ref[...] = acc
 
     @pl.when(jc == pl.num_programs(2) - 1)
     def _store():
-        o_ref[...] = acc_ref[...]
+        o_ref[0] = acc_ref[...]
+
+
+def _live_idx(idx: jax.Array, count: jax.Array) -> jax.Array:
+    """(NB, P) survivor indices with lanes ``>= count`` set to -1."""
+    lane = jnp.arange(idx.shape[-1], dtype=jnp.int32)
+    return jnp.where(lane[None, :] < count[:, None], idx, -1)
 
 
 @functools.partial(
@@ -152,8 +139,8 @@ def csb_mvm_pallas(
 ) -> jax.Array:
     """Returns (B, Br*bm) fp32. ``group`` = blocks fused per grid step.
 
-    ``interpret=None`` resolves from ``jax.default_backend()``: real
-    accelerators compile the kernel, CPU keeps interpret mode."""
+    ``interpret=None`` resolves from ``jax.default_backend()``: the TPU
+    compiles the kernel, other backends keep interpret mode."""
     if interpret is None:
         interpret = default_interpret()
     br, bc = grid
@@ -163,29 +150,31 @@ def csb_mvm_pallas(
     assert bc % group == 0, (bc, group)
     b = x.shape[0]
     assert b % batch_tile == 0, (b, batch_tile)
+    if not interpret and batch_tile % 8 and batch_tile != b:
+        raise ValueError(
+            f"batch_tile={batch_tile} breaks the TPU tiling rule: a "
+            f"block's second-minor dim must be divisible by 8 or equal "
+            f"the array's ({b} rows here)")
 
+    xb = x.reshape(b, bc, bn).transpose(1, 0, 2)             # (Bc, B, bn)
     vals4 = vals.reshape(br, bc, pm, pn)
-    ridx3 = row_idx.reshape(br, bc, pm)
-    cidx3 = col_idx.reshape(br, bc, pn)
-    m2 = m.reshape(br, bc)
-    n2 = n.reshape(br, bc)
+    ridx4 = _live_idx(row_idx, m).reshape(br, bc, 1, pm)
+    cidx4 = _live_idx(col_idx, n).reshape(br, bc, 1, pn)
 
-    gsteps = bc // group
     out = pl.pallas_call(
-        functools.partial(_kernel, bm=bm, bn=bn, group=group),
-        grid=(b // batch_tile, br, gsteps),
+        functools.partial(_kernel, group=group),
+        grid=(b // batch_tile, br, bc // group),
         in_specs=[
-            pl.BlockSpec((batch_tile, group * bn),
-                         lambda t, i, j: (t, j)),
+            pl.BlockSpec((group, batch_tile, bn),
+                         lambda t, i, j: (j, t, 0)),
             pl.BlockSpec((1, group, pm, pn), lambda t, i, j: (i, j, 0, 0)),
-            pl.BlockSpec((1, group, pm), lambda t, i, j: (i, j, 0)),
-            pl.BlockSpec((1, group, pn), lambda t, i, j: (i, j, 0)),
-            pl.BlockSpec((1, group), lambda t, i, j: (i, j)),
-            pl.BlockSpec((1, group), lambda t, i, j: (i, j)),
+            pl.BlockSpec((1, group, 1, pm), lambda t, i, j: (i, j, 0, 0)),
+            pl.BlockSpec((1, group, 1, pn), lambda t, i, j: (i, j, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((batch_tile, bm), lambda t, i, j: (t, i)),
-        out_shape=jax.ShapeDtypeStruct((b, br * bm), jnp.float32),
+        out_specs=pl.BlockSpec((1, batch_tile, bm),
+                               lambda t, i, j: (i, t, 0)),
+        out_shape=jax.ShapeDtypeStruct((br, b, bm), jnp.float32),
         scratch_shapes=[pltpu.VMEM((batch_tile, bm), jnp.float32)],
         interpret=interpret,
-    )(x, vals4, ridx3, cidx3, m2, n2)
-    return out
+    )(xb, vals4, ridx4, cidx4)
+    return out.transpose(1, 0, 2).reshape(b, br * bm)

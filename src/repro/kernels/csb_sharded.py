@@ -39,23 +39,6 @@ from repro.core.csb_format import ShardedCSB, csb_output_permutation
 from .csb_mvm import csb_mvm_pallas, default_interpret
 from .ops import pad_to_grid
 
-try:                                      # jax >= 0.6: top-level API
-    from jax import shard_map as _shard_map
-except ImportError:                       # pragma: no cover - older jax
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-
-def _shmap(f, mesh, in_specs, out_specs):
-    """shard_map across jax versions: pallas_call has no replication
-    rule, so the check must be off — the knob is ``check_rep`` on older
-    jax and ``check_vma`` after the rename."""
-    try:
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_rep=False)
-    except TypeError:
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_vma=False)
-
 
 def _chunk_bounds(rpd: int, overlap: int) -> list[tuple[int, int]]:
     """Split ``rpd`` block-rows into ``overlap`` contiguous chunks,
@@ -135,10 +118,11 @@ def _sharded_fn(mesh, axis_name: str, grid: tuple[int, int],
             return parts[0]
         return jnp.concatenate(parts, axis=1)        # (Bp, D*rpd*bm)
 
-    shmapped = _shmap(
-        body, mesh,
+    # pallas_call has no replication rule, so the check must be off
+    shmapped = jax.shard_map(
+        body, mesh=mesh,
         in_specs=(spec1, spec1, spec1, spec1, spec1, xspec),
-        out_specs=xspec,
+        out_specs=xspec, check_vma=False,
     )
 
     # perm: original output row -> position in the device-order gather;

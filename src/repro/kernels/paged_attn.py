@@ -28,8 +28,8 @@ MLA routes through the same kernel via the optional rope score term:
 and the value pool is the ``c_kv`` pool itself — standard MHA with one
 KV group and a value width different from the key width.
 
-``interpret`` selection mirrors ``csb_mvm.default_interpret``: TPU/GPU
-compile, CPU interprets, and the CI golden lane
+``interpret`` selection mirrors ``csb_mvm.default_interpret``: the TPU
+compiles, the CPU interprets, and the CI golden lane
 (REPRO_FORCE_TPU_INTERPRET=1) takes the compiled branch under
 ``pltpu.force_tpu_interpret_mode``.
 """

@@ -22,7 +22,8 @@ def densify(p: PaddedCSB) -> jax.Array:
     roh = jax.nn.one_hot(p.row_idx, bm, dtype=p.vals.dtype) * rmask[..., None]
     coh = jax.nn.one_hot(p.col_idx, bn, dtype=p.vals.dtype) * cmask[..., None]
     # scatter kernel (Pm,Pn) into the (bm,bn) block frame
-    blocks = jnp.einsum("bkr,bkl,blc->brc", roh, p.vals, coh)
+    blocks = jnp.einsum("bkr,bkl,blc->brc", roh, p.vals, coh,
+                        precision=jax.lax.Precision.HIGHEST)
     w = blocks.reshape(br, bc, bm, bn).transpose(0, 2, 1, 3)
     w = w.reshape(br * bm, bc * bn)
     return w[: p.shape[0], : p.shape[1]]
@@ -31,8 +32,9 @@ def densify(p: PaddedCSB) -> jax.Array:
 def csb_mvm_ref(p: PaddedCSB, x: jax.Array) -> jax.Array:
     """y = x @ W^T with W the CSB matrix; x: (..., in_dim) -> (..., out_dim).
 
-    Accumulates in fp32 like the kernel does.
+    Accumulates in fp32 at highest precision, like the kernel does.
     """
     w = densify(p).astype(jnp.float32)
-    y = jnp.einsum("...i,oi->...o", x.astype(jnp.float32), w)
+    y = jnp.einsum("...i,oi->...o", x.astype(jnp.float32), w,
+                   precision=jax.lax.Precision.HIGHEST)
     return y.astype(x.dtype)

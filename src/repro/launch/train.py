@@ -27,6 +27,7 @@ from repro.data import CharLMTask, lm_batch_iterator, sharded_batches
 from repro.dist import (
     ShardingPolicy, activation_rules, batch_specs, param_specs, use_rules,
 )
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.shardspec import to_named
 from repro.models import forward_loss, init_params
 from repro.optim import linear_warmup_cosine
@@ -58,6 +59,7 @@ def main() -> None:
     ap.add_argument("--lr", type=float, default=1e-3)
     ap.add_argument("--ckpt", default=None)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_reduced(args.arch) if args.reduced else get_config(args.arch)
     if args.reduced and cfg.n_img_tokens:

@@ -19,17 +19,10 @@ def _tpu_interpret_golden():
     """CI golden lane: REPRO_FORCE_TPU_INTERPRET=1 runs every Pallas
     call through pltpu.force_tpu_interpret_mode, so the compiled-path
     branch of kernels.csb_mvm.default_interpret (interpret=False, the
-    TPU route) is exercised on CPU runners. On a jax without the
-    context manager this degrades to the plain interpret path (see
-    default_interpret)."""
+    TPU route) is exercised on CPU runners."""
     if os.environ.get("REPRO_FORCE_TPU_INTERPRET", "0") in ("", "0"):
         yield
         return
-    try:
-        from jax.experimental.pallas import tpu as pltpu
-        cm = pltpu.force_tpu_interpret_mode()
-    except (ImportError, AttributeError):
-        yield
-        return
-    with cm:
+    from jax.experimental.pallas import tpu as pltpu
+    with pltpu.force_tpu_interpret_mode():
         yield
