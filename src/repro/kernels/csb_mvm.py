@@ -176,5 +176,7 @@ def csb_mvm_pallas(
         out_shape=jax.ShapeDtypeStruct((br, b, bm), jnp.float32),
         scratch_shapes=[pltpu.VMEM((batch_tile, bm), jnp.float32)],
         interpret=interpret,
+        # the op's name in HLO and in a profile, whatever wraps the call
+        name="csb_mvm_pallas",
     )(xb, vals4, ridx4, cidx4)
     return out.transpose(1, 0, 2).reshape(b, br * bm)

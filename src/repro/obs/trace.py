@@ -7,12 +7,22 @@ bump plus a tuple store, never a list growth, so a multi-minute serve
 run traces at a bounded memory footprint (the oldest events fall off;
 ``dropped`` counts them).
 
-Tracing is **off by default** and the disabled path is a no-op fast
-path: module-level helpers read one global, compare against ``None``
-and return a shared singleton — no dict, no tuple, no timestamps
-(``tests/test_obs.py`` asserts the disabled hot path is
-allocation-free). Instrumented code therefore stays on the gated perf
-paths (``serve/*/us_per_token``) without moving them.
+Spans have two sinks. The ring above is on after :func:`enable`. The
+second is the JAX profiler's own trace: while a profiler session is
+active (``jax.profiler.trace`` / ``start_trace``), :func:`span` also
+opens a ``jax.profiler.TraceAnnotation`` of the same name, so the span
+lands in the session's ``.xplane.pb`` beside the device's operations,
+on the same clock. Only the name goes there; ``track`` and ``args`` are
+the ring's. :func:`instant` and :func:`complete` stay ring-only: the
+profiler takes a span as it happens and cannot be handed one after the
+fact.
+
+Both sinks are **off by default** and the disabled path is a no-op
+fast path: :func:`span` reads one global and asks the profiler whether
+a session is active, then returns a shared singleton — no dict, no
+tuple, no timestamps (``tests/test_obs.py`` asserts the disabled hot
+path is allocation-free). Instrumented code therefore stays on the
+gated perf paths (``serve/*/us_per_token``) without moving them.
 
 Export targets the Chrome ``trace_event`` JSON format (the
 ``traceEvents`` array of ``ph``/``ts``/``pid``/``tid``/``name``
@@ -42,6 +52,11 @@ import os
 import threading
 import time
 
+from jax.profiler import TraceAnnotation
+
+# True while a JAX profiler session is active: the profiler sink's gate
+_profiler_on = TraceAnnotation.is_enabled
+
 
 class _NullSpan:
     """Shared do-nothing context manager the disabled paths hand out."""
@@ -59,24 +74,32 @@ _NULL_SPAN = _NullSpan()
 
 
 class _Span:
-    """Context-manager handle pairing one ``begin`` with its ``end``."""
+    """Context-manager handle pairing one ``begin`` with its ``end``, in
+    the ring of ``tracer`` and, given ``annotation``, the profiler's
+    trace; either may be None."""
 
-    __slots__ = ("_tracer", "_name", "_track", "_args", "_t0")
+    __slots__ = ("_tracer", "_name", "_track", "_args", "_t0", "_ann")
 
-    def __init__(self, tracer, name, track, args):
+    def __init__(self, tracer, name, track, args, annotation=None):
         self._tracer = tracer
         self._name = name
         self._track = track
         self._args = args
+        self._ann = annotation
 
     def __enter__(self):
+        if self._ann is not None:
+            self._ann.__enter__()
         self._t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, *exc):
         t1 = time.perf_counter_ns()
-        self._tracer._record("X", self._name, self._t0, t1 - self._t0,
-                             self._track, self._args)
+        if self._tracer is not None:
+            self._tracer._record("X", self._name, self._t0, t1 - self._t0,
+                                 self._track, self._args)
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
         return False
 
 
@@ -109,7 +132,8 @@ class Tracer:
 
     def span(self, name: str, track: str | None = None,
              args: dict | None = None) -> _Span:
-        """Context manager timing its ``with`` body as one X event."""
+        """Context manager timing its ``with`` body as one X event in
+        this tracer's ring (the module's :func:`span` feeds every sink)."""
         return _Span(self, name, track, args)
 
     def begin(self, name: str, track: str | None = None,
@@ -136,7 +160,8 @@ class Tracer:
     def complete(self, name: str, t0_ns: int, dur_ns: int,
                  track: str | None = None, args: dict | None = None) -> None:
         """Record an externally-timed span (timestamps from
-        :meth:`now_ns`) — zero timing overhead at the measured site."""
+        :meth:`now_ns`) — zero timing overhead at the measured site.
+        Ring only: the profiler's trace takes no span after the fact."""
         self._record("X", name, t0_ns, dur_ns, track, args)
 
     def now_ns(self) -> int:
@@ -235,13 +260,19 @@ def get() -> Tracer | None:
 
 
 def span(name, track=None, args=None):
+    """Context manager timing its ``with`` body in every sink that is
+    on: the ring (after :func:`enable`) and the JAX profiler's trace
+    (while a session is active). With neither, the shared no-op span."""
     t = _tracer
+    if _profiler_on():
+        return _Span(t, name, track, args, TraceAnnotation(name))
     if t is None:
         return _NULL_SPAN
     return t.span(name, track, args)
 
 
 def instant(name, track=None, args=None):
+    """A point event in the ring; the profiler's trace takes none."""
     t = _tracer
     if t is None:
         return

@@ -793,6 +793,15 @@ def shard_cell_params(params: dict, mesh, axis_name: str = "model") -> dict:
         out, specs)
 
 
+def make_frame_step(graph: CellGraph):
+    """The jitted one-frame step ``(params, state, x_t) -> (y, state)``
+    of ``graph``; its program is ``jit_frame_step`` in a profile."""
+    def frame_step(p, st, x):
+        return cell_apply(graph, p, x, st)
+
+    return jax.jit(frame_step)
+
+
 def rnn_serve_frames(graph: CellGraph, params: PyTree, frames,
                      state: PyTree | None = None,
                      warmup: int | None = None,
@@ -820,75 +829,85 @@ def rnn_serve_frames(graph: CellGraph, params: PyTree, frames,
     pipeline, so the MEAN of these is pessimistic — the un-blocked
     ``us_per_frame`` stays the throughput number; the per-frame vector
     is for tail latency (p99) reporting, where realtime audio cares
-    about the worst frame, not the average."""
-    fcfg = resolve_config(config, caller="rnn_serve_frames")
-    if warmup is None:
-        warmup = fcfg.frame_warmup
-    if collect_frame_times is None:
-        collect_frame_times = fcfg.collect_frame_times
-    mesh = _resolve_mesh(mesh)
-    rules = current_rules()
-    if mesh is not None:
-        if axis_name in tuple(mesh.axis_names) \
-                and mesh.shape[axis_name] > 1:
-            params = shard_cell_params(params, mesh, axis_name)
-        frames = jnp.asarray(frames)
-        frames = jax.device_put(frames, NamedSharding(    # (T, B, in): B=dp
-            mesh, _dp_spec(mesh, frames.shape, batch_axis=1)))
-        if rules is None or rules.mesh is not mesh:
-            rules = Rules({}, mesh=mesh)
-    if rules is None:
-        rules = Rules({})
+    about the worst frame, not the average.
 
-    if state is None:
-        state = init_state(graph, frames.shape[1:-1], jnp.float32)
+    Each call opens a ``serve/frames/call`` span and one span per phase
+    inside it (``prepare``, ``warmup``, ``dispatch``, ``sync``,
+    ``stack``) through :func:`repro.obs.trace.span`: no-ops unless the
+    ring tracer is on or a JAX profiler session is active."""
+    with obs_trace.span("serve/frames/call"):
+        with obs_trace.span("serve/frames/prepare"):
+            fcfg = resolve_config(config, caller="rnn_serve_frames")
+            if warmup is None:
+                warmup = fcfg.frame_warmup
+            if collect_frame_times is None:
+                collect_frame_times = fcfg.collect_frame_times
+            mesh = _resolve_mesh(mesh)
+            rules = current_rules()
+            if mesh is not None:
+                if axis_name in tuple(mesh.axis_names) \
+                        and mesh.shape[axis_name] > 1:
+                    params = shard_cell_params(params, mesh, axis_name)
+                frames = jnp.asarray(frames)      # (T, B, in): B=dp
+                frames = jax.device_put(frames, NamedSharding(
+                    mesh, _dp_spec(mesh, frames.shape, batch_axis=1)))
+                if rules is None or rules.mesh is not mesh:
+                    rules = Rules({}, mesh=mesh)
+            if rules is None:
+                rules = Rules({})
 
-    @jax.jit
-    def step(p, st, x):
-        y, st2 = cell_apply(graph, p, x, st)
-        return y, st2
+            if state is None:
+                state = init_state(graph, frames.shape[1:-1], jnp.float32)
 
-    with use_rules(rules):
-        # warmup / compile
-        for _ in range(warmup):
-            y, _ = step(params, state, frames[0])
-        y.block_until_ready()
+            step = make_frame_step(graph)
 
-        outs = []
-        t0 = time.perf_counter()
-        st = state
-        for t in range(frames.shape[0]):
-            y, st = step(params, st, frames[t])
-            outs.append(y)
-        jax.block_until_ready(outs[-1])
-        dt = time.perf_counter() - t0
+        with use_rules(rules):
+            with obs_trace.span("serve/frames/warmup"):
+                # the fresh step's trace, lowering and compile
+                for _ in range(warmup):
+                    y, _ = step(params, state, frames[0])
+                y.block_until_ready()
 
-        frame_us = None
-        if collect_frame_times:
-            # separate per-frame-blocking pass so the throughput number
-            # above is untouched by the serialization; per-frame spans
-            # and the realtime histogram (serve/frames/wall_us — the
-            # distribution the 500us budget judges) come from HERE,
-            # measured times recorded after the fact so tracing adds
-            # zero overhead inside the timed region
-            tr = obs_trace.get()
-            reg = obs_metrics.get()
-            times = np.empty(frames.shape[0])
-            st2 = state
-            for t in range(frames.shape[0]):
-                f0 = time.perf_counter_ns()
-                y2, st2 = step(params, st2, frames[t])
-                jax.block_until_ready((y2, st2))
-                dur = time.perf_counter_ns() - f0
-                times[t] = dur / 1e3
-                if tr is not None:
-                    tr.complete("serve/frame", f0, dur, track="frames",
-                                args={"frame": t})
-                if reg is not None:
-                    reg.histogram("serve/frames/wall_us").observe(
-                        dur / 1e3)
-            frame_us = times
+            outs = []
+            t0 = time.perf_counter()
+            st = state
+            with obs_trace.span("serve/frames/dispatch"):
+                for t in range(frames.shape[0]):
+                    y, st = step(params, st, frames[t])
+                    outs.append(y)
+            with obs_trace.span("serve/frames/sync"):
+                jax.block_until_ready(outs[-1])
+            dt = time.perf_counter() - t0
+
+            frame_us = None
+            if collect_frame_times:
+                # separate per-frame-blocking pass so the throughput
+                # number above is untouched by the serialization;
+                # per-frame spans and the realtime histogram
+                # (serve/frames/wall_us — the distribution the 500us
+                # budget judges) come from HERE, measured times
+                # recorded after the fact so tracing adds zero overhead
+                # inside the timed region
+                tr = obs_trace.get()
+                reg = obs_metrics.get()
+                times = np.empty(frames.shape[0])
+                st2 = state
+                for t in range(frames.shape[0]):
+                    f0 = time.perf_counter_ns()
+                    y2, st2 = step(params, st2, frames[t])
+                    jax.block_until_ready((y2, st2))
+                    dur = time.perf_counter_ns() - f0
+                    times[t] = dur / 1e3
+                    if tr is not None:
+                        tr.complete("serve/frame", f0, dur, track="frames",
+                                    args={"frame": t})
+                    if reg is not None:
+                        reg.histogram("serve/frames/wall_us").observe(
+                            dur / 1e3)
+                frame_us = times
+        with obs_trace.span("serve/frames/stack"):
+            ys = jnp.stack(outs)
     us_per_frame = dt / frames.shape[0] * 1e6
     if collect_frame_times:
-        return jnp.stack(outs), st, us_per_frame, frame_us
-    return jnp.stack(outs), st, us_per_frame
+        return ys, st, us_per_frame, frame_us
+    return ys, st, us_per_frame

@@ -322,6 +322,81 @@ def test_rnn_serve_frames_spans():
     assert reg.histogram("serve/frames/wall_us").count == 5
 
 
+FRAME_PHASES = ("prepare", "warmup", "dispatch", "sync", "stack")
+
+
+def _serve_tiny_frames():
+    from repro.cells import init_params as cell_init, make_cell
+    from repro.serve import rnn_serve_frames
+    cell = make_cell("lstm", 8, 16)
+    params = cell_init(cell, jax.random.PRNGKey(2))
+    frames = jax.random.normal(jax.random.PRNGKey(3), (5, 2, 8))
+    for _ in range(2):
+        rnn_serve_frames(cell, params, frames, warmup=1)
+
+
+def _profiled_frames(tmp_path) -> dict:
+    """Two frame-server calls under a profiler session; the host spans
+    of the ``.xplane.pb`` as {name: [(start, end)]}, read back by the
+    chip benchmark's own trace reduction."""
+    spec = importlib.util.spec_from_file_location(
+        "trace_reduce", os.path.join(os.path.dirname(__file__), "..",
+                                     "benchmarks", "chip", "trace_reduce.py"))
+    trace_reduce = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(trace_reduce)
+    with jax.profiler.trace(str(tmp_path)):
+        _serve_tiny_frames()
+    out: dict = {}
+    for evs in trace_reduce.load(tmp_path).host.values():
+        for name, start, dur in evs:
+            out.setdefault(name, []).append((start, start + dur))
+    return out
+
+
+def test_frame_phase_spans_in_profiler_trace(tmp_path):
+    assert trace.get() is None                 # the ring stays off
+    host = _profiled_frames(tmp_path)
+    calls = sorted(host["serve/frames/call"])
+    assert len(calls) == 2
+    for phase in FRAME_PHASES:
+        spans = sorted(host[f"serve/frames/{phase}"])
+        assert len(spans) == 2, phase
+        for (a, b), (lo, hi) in zip(spans, calls):
+            assert lo <= a <= b <= hi, phase    # inside its own call
+    # the phases follow one another in each call
+    for i in range(2):
+        ends = [sorted(host[f"serve/frames/{p}"])[i] for p in FRAME_PHASES]
+        assert all(e[1] <= f[0] for e, f in zip(ends, ends[1:]))
+
+
+def test_frame_phase_spans_reach_both_sinks(tmp_path):
+    tr = trace.enable()
+    host = _profiled_frames(tmp_path)
+    names = ["serve/frames/call"] + [f"serve/frames/{p}"
+                                     for p in FRAME_PHASES]
+    ring = [e[1] for e in tr.events() if e[0] == "X"]
+    for name in names:
+        assert ring.count(name) == 2 and len(host[name]) == 2, name
+
+
+def test_frame_phase_spans_off_records_nothing(monkeypatch):
+    asked = []
+    real = trace.span
+
+    def spy(name, track=None, args=None):
+        s = real(name, track, args)
+        asked.append((name, s))
+        return s
+
+    monkeypatch.setattr(trace, "span", spy)
+    assert trace.get() is None
+    assert not jax.profiler.TraceAnnotation.is_enabled()
+    _serve_tiny_frames()
+    assert [n for n, _ in asked].count("serve/frames/call") == 2
+    assert len(asked) == 12
+    assert all(s is trace._NULL_SPAN for _, s in asked)
+
+
 def test_train_loop_step_spans():
     from repro.train import TrainConfig, train
     tr, reg = obs.enable_all()
