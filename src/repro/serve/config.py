@@ -57,7 +57,9 @@ class EngineConfig:
                            auto-off for SSD/hybrid mixers).
 
     Frame serving (``rnn_serve_frames``):
-      ``frame_warmup``         — compile/warmup steps before timing.
+      ``frame_warmup``         — steps before timing, when the step is
+                                 fresh (its first call with these
+                                 argument shapes and shardings).
       ``collect_frame_times``  — per-frame blocking latency pass.
     """
 

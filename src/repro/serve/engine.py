@@ -30,6 +30,7 @@ tests use.
 """
 from __future__ import annotations
 
+import collections
 import dataclasses
 import functools
 import math
@@ -802,6 +803,61 @@ def make_frame_step(graph: CellGraph):
     return jax.jit(frame_step)
 
 
+# The frame server's jitted steps, one per key, least recently used
+# first. jit caches by function identity, so a step built anew each call
+# would trace and lower anew each call; a few dozen keys cover a process
+# that serves a handful of cells (the test suite builds hundreds).
+_STEP_CACHE_SIZE = 32
+_step_cache: collections.OrderedDict = collections.OrderedDict()
+
+
+def _graph_key(graph: CellGraph) -> tuple:
+    """What ``cell_apply`` reads of ``graph``, hashable (``next_state``
+    is a dict)."""
+    return (graph.name, graph.input_dim, graph.hidden_dim, graph.ops,
+            graph.state_vars, tuple(sorted(graph.next_state.items())),
+            graph.output)
+
+
+def _frame_step_for(graph: CellGraph) -> tuple[Any, set]:
+    """The cached jitted step of ``graph`` and the argument signatures
+    it has run with, built by :func:`make_frame_step` on a miss.
+
+    The key is what the step's trace reads besides its arguments: the
+    graph's structure, the ``cell_apply`` it calls (a module global,
+    looked up when it traces), the active model mesh (``ShardedCSB``
+    weights route by it) and the kernel's interpret mode. jit's own
+    cache keys the rest (shapes, dtypes, shardings)."""
+    from repro.core.csb_linear import _active_model_mesh
+    from repro.kernels import ops
+
+    key = (_graph_key(graph), cell_apply, _active_model_mesh(),
+           ops.default_interpret())
+    entry = _step_cache.get(key)
+    hit = entry is not None
+    if hit:
+        _step_cache.move_to_end(key)
+    else:
+        entry = _step_cache[key] = (make_frame_step(graph), set())
+        if len(_step_cache) > _STEP_CACHE_SIZE:
+            _step_cache.popitem(last=False)
+    reg = obs_metrics.get()
+    if reg is not None:
+        reg.counter("serve/frames/step_cache/"
+                    + ("hit" if hit else "miss")).inc()
+    return entry
+
+
+def _arg_signature(tree: PyTree) -> tuple:
+    """Tree structure plus each leaf's shape, dtype and placement: the
+    arguments jit would compile anew for."""
+    leaves, treedef = jax.tree.flatten(tree)
+    return treedef, tuple(
+        (type(x), np.shape(x), getattr(x, "dtype", None),
+         getattr(x, "weak_type", None), getattr(x, "sharding", None),
+         getattr(x, "committed", None)) for x in leaves)
+
+
 def rnn_serve_frames(graph: CellGraph, params: PyTree, frames,
                      state: PyTree | None = None,
                      warmup: int | None = None,
@@ -815,6 +871,14 @@ def rnn_serve_frames(graph: CellGraph, params: PyTree, frames,
     :class:`EngineConfig` homes of the two knobs; the positional
     ``warmup`` and ``collect_frame_times`` arguments override them when
     given explicitly (both default to the config).
+
+    The jitted step is built once per graph structure, model mesh and
+    kernel interpret mode and reused across calls (a small LRU). The
+    ``warmup`` steps run before timing only when the step is fresh to
+    these arguments: its first call with this tree structure and these
+    leaf shapes, dtypes and shardings of ``(params, state, frames)``,
+    i.e. when the step would trace, lower and compile. On later calls
+    nothing compiles and no warm-up step runs.
 
     With ``mesh=`` (or an active Rules mesh with a non-trivial "model"
     axis) the CSB weights are partitioned over the model axis and the
@@ -859,14 +923,20 @@ def rnn_serve_frames(graph: CellGraph, params: PyTree, frames,
             if state is None:
                 state = init_state(graph, frames.shape[1:-1], jnp.float32)
 
-            step = make_frame_step(graph)
+            with use_rules(rules):
+                step, seen = _frame_step_for(graph)
+            sig = _arg_signature((params, state, frames))
+            fresh = sig not in seen
 
         with use_rules(rules):
             with obs_trace.span("serve/frames/warmup"):
-                # the fresh step's trace, lowering and compile
-                for _ in range(warmup):
-                    y, _ = step(params, state, frames[0])
-                y.block_until_ready()
+                # a fresh step's trace, lowering and compile; empty when
+                # the step has run with these arguments before
+                if fresh and warmup:
+                    for _ in range(warmup):
+                        y, _ = step(params, state, frames[0])
+                    y.block_until_ready()
+                seen.add(sig)
 
             outs = []
             t0 = time.perf_counter()

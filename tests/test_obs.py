@@ -11,6 +11,7 @@ The pins that matter:
 * every exported event carries the Chrome ``trace_event`` required
   fields, so the file loads in Perfetto unmodified.
 """
+import collections
 import importlib.util
 import json
 import os
@@ -353,9 +354,16 @@ def _profiled_frames(tmp_path) -> dict:
     return out
 
 
-def test_frame_phase_spans_in_profiler_trace(tmp_path):
+def test_frame_phase_spans_in_profiler_trace(tmp_path, monkeypatch):
+    from repro.serve import engine
+    monkeypatch.setattr(engine, "_step_cache", collections.OrderedDict())
+    reg = metrics.enable()
     assert trace.get() is None                 # the ring stays off
     host = _profiled_frames(tmp_path)
+    # the second call reuses the first's step: nothing to warm up, yet
+    # every phase is there, so the span readers read numbers, not None
+    assert reg.counter("serve/frames/step_cache/miss").value == 1
+    assert reg.counter("serve/frames/step_cache/hit").value == 1
     calls = sorted(host["serve/frames/call"])
     assert len(calls) == 2
     for phase in FRAME_PHASES:
