@@ -1,6 +1,8 @@
 """The four RNN cell types the paper evaluates (Table 1): LSTM, GRU,
 LSTMP (LSTM w/ recurrent projection, Sak et al.) and Li-GRU (Ravanelli
-et al.), each expressed as a dataflow graph over the paper's primitives.
+et al.), each expressed as a dataflow graph over the paper's primitives;
+and the layer-normalised LSTMP of streaming speech transducers (He et
+al. 2019).
 """
 from __future__ import annotations
 
@@ -46,6 +48,27 @@ def lstmp(input_dim: int, hidden_dim: int, proj_dim: int) -> CellGraph:
     return g.build(("h", "c"), {"h": h_new, "c": c_new}, h_new)
 
 
+def lnlstmp(input_dim: int, hidden_dim: int, proj_dim: int) -> CellGraph:
+    """LSTMP with layer normalisation (Ba et al. 2016), as the RNN-T of He
+    et al. 2019 uses it: each gate's summed input W x + U h is normalised
+    over its hidden units, the norm's bias being the gate's bias, before
+    the gate's nonlinearity; c is normalised before the output tanh."""
+    g = GraphBuilder("lnlstmp", input_dim, hidden_dim)
+    x, h, c = g.input("x"), g.input("h"), g.input("c")  # h: (proj_dim,)
+
+    def gate(k, act):
+        s = g.add(g.mvm(f"W_{k}", x, hidden_dim, input_dim),
+                  g.mvm(f"U_{k}", h, hidden_dim, proj_dim))
+        return getattr(g, act)(g.layernorm(f"ln_{k}", s, hidden_dim))
+
+    i, f, o = (gate(k, "sigmoid") for k in "ifo")
+    gg = gate("g", "tanh")
+    c_new = g.add(g.mul(f, c), g.mul(i, gg))
+    m = g.mul(o, g.tanh(g.layernorm("ln_c", c_new, hidden_dim)))
+    h_new = g.mvm("W_proj", m, proj_dim, hidden_dim)
+    return g.build(("h", "c"), {"h": h_new, "c": c_new}, h_new)
+
+
 def ligru(input_dim: int, hidden_dim: int) -> CellGraph:
     """Light GRU: no reset gate, ReLU candidate (batch-norm folded)."""
     g = GraphBuilder("ligru", input_dim, hidden_dim)
@@ -63,11 +86,13 @@ CELL_BUILDERS = {
     "gru": gru,
     "lstmp": lstmp,
     "ligru": ligru,
+    "lnlstmp": lnlstmp,
 }
 
 
 def make_cell(kind: str, input_dim: int, hidden_dim: int,
               proj_dim: int | None = None) -> CellGraph:
-    if kind == "lstmp":
-        return lstmp(input_dim, hidden_dim, proj_dim or hidden_dim // 2)
+    if kind in ("lstmp", "lnlstmp"):
+        return CELL_BUILDERS[kind](input_dim, hidden_dim,
+                                   proj_dim or hidden_dim // 2)
     return CELL_BUILDERS[kind](input_dim, hidden_dim)

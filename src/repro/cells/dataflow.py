@@ -2,7 +2,9 @@
 
 An RNN cell is a DAG of the paper's arithmetic primitives — MVM
 (CSB-Engine), element-wise mul/add, sigmoid, tanh (+ relu and 1-x, needed
-by Li-GRU/GRU). The same graph object serves three consumers:
+by Li-GRU/GRU, and a layer normalisation over the hidden units, needed by
+the LN-LSTMP of speech transducers). The same graph object serves three
+consumers:
 
 1. the **executor** (`cell_apply`) — a small interpreter that traces the
    DAG into a jaxpr, so every cell type runs on one code path (the paper's
@@ -26,7 +28,9 @@ import numpy as np
 from repro.core.csb_format import PaddedCSB, ShardedCSB
 
 KINDS = ("input", "mvm", "bias", "add", "mul",
-         "sigmoid", "tanh", "relu", "one_minus")
+         "sigmoid", "tanh", "relu", "one_minus", "layernorm")
+# variance floor of the layernorm op (Ba et al. 2016 give none)
+LN_EPS = 1e-5
 
 
 @dataclasses.dataclass(frozen=True)
@@ -34,10 +38,21 @@ class Op:
     name: str
     kind: str
     inputs: tuple[str, ...] = ()
-    shape: tuple[int, int] | None = None  # (out, in) for mvm; (out,) bias
+    shape: tuple[int, int] | None = None  # (out, in) mvm; (out,) bias, LN
 
     def __post_init__(self):
         assert self.kind in KINDS, self.kind
+
+    @property
+    def weights(self) -> dict[str, tuple[int, ...]]:
+        """The op's weights by name: an mvm's matrix and a bias carry the
+        op's name; a layernorm has a gain ``<name>_g`` and a bias
+        ``<name>_b``."""
+        if self.kind in ("mvm", "bias"):
+            return {self.name: self.shape}
+        if self.kind == "layernorm":
+            return {f"{self.name}_g": self.shape, f"{self.name}_b": self.shape}
+        return {}
 
 
 @dataclasses.dataclass(frozen=True)
@@ -59,14 +74,21 @@ class CellGraph:
         raise KeyError(name)
 
     @property
+    def key(self) -> tuple:
+        """What ``cell_apply`` reads of the graph, hashable
+        (``next_state`` is a dict)."""
+        return (self.name, self.input_dim, self.hidden_dim, self.ops,
+                self.state_vars, tuple(sorted(self.next_state.items())),
+                self.output)
+
+    @property
     def mvm_ops(self) -> tuple[Op, ...]:
         return tuple(o for o in self.ops if o.kind == "mvm")
 
     def weight_shapes(self) -> dict[str, tuple[int, ...]]:
         out = {}
         for o in self.ops:
-            if o.kind in ("mvm", "bias"):
-                out[o.name] = o.shape
+            out.update(o.weights)
         return out
 
     def param_count(self) -> int:
@@ -115,6 +137,11 @@ class GraphBuilder:
 
     def one_minus(self, a: str) -> str:
         return self._emit("one_minus", (a,))
+
+    def layernorm(self, name: str, x: str, dim: int) -> str:
+        """(x - mean) / sqrt(var + LN_EPS) * gain + bias over the last
+        axis; the weights are ``<name>_g`` and ``<name>_b``."""
+        return self._emit("layernorm", (x,), (dim,), name=name)
 
     def gate(self, prefix: str, x: str, h: str, act: str,
              in_dim: int, hid: int, out_dim: int | None = None) -> str:
@@ -184,10 +211,21 @@ def cell_apply(
             env[op.name] = jax.nn.relu(a)
         elif op.kind == "one_minus":
             env[op.name] = 1.0 - a
+        elif op.kind == "layernorm":
+            env[op.name] = layernorm(a, params[f"{op.name}_g"],
+                                     params[f"{op.name}_b"])
         else:  # pragma: no cover
             raise ValueError(op.kind)
     new_state = {k: env[v] for k, v in graph.next_state.items()}
     return env[graph.output], new_state
+
+
+def layernorm(x: jax.Array, gain: jax.Array, bias: jax.Array) -> jax.Array:
+    """Layer normalisation over the last axis (Ba et al. 2016)."""
+    mu = jnp.mean(x, axis=-1, keepdims=True)
+    var = jnp.mean(jnp.square(x - mu), axis=-1, keepdims=True)
+    return ((x - mu) * jax.lax.rsqrt(var + LN_EPS) * gain.astype(x.dtype)
+            + bias.astype(x.dtype))
 
 
 def init_state(graph: CellGraph, batch_shape: tuple[int, ...],
@@ -206,9 +244,12 @@ def init_state(graph: CellGraph, batch_shape: tuple[int, ...],
 def init_params(graph: CellGraph, key: jax.Array,
                 dtype=jnp.float32, scale: float | None = None) -> dict:
     params = {}
+    gains = {f"{o.name}_g" for o in graph.ops if o.kind == "layernorm"}
     for name, shape in graph.weight_shapes().items():
         key, sub = jax.random.split(key)
-        if len(shape) == 1:
+        if name in gains:
+            params[name] = jnp.ones(shape, dtype)
+        elif len(shape) == 1:
             params[name] = jnp.zeros(shape, dtype)
         else:
             s = scale or (1.0 / np.sqrt(shape[1]))

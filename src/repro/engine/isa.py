@@ -31,6 +31,18 @@ ALL_UNITS = ("LoadUnit", "CSB-Engine", "Sum1", "Sum2", "Sigmoid",
              "Tanh", "Mult1", "Mult2", "StoreUnit")
 
 
+class UnsupportedOpError(ValueError):
+    """A cell op that no unit of the datapath (paper Fig. 8) executes."""
+
+
+# op kinds the datapath has no unit for, and why
+UNSUPPORTED: dict[str, str] = {
+    "layernorm": "needs the mean and variance over all hidden units, and "
+                 "every unit of Fig. 8 works element by element; no unit "
+                 "reduces across a vector",
+}
+
+
 @dataclasses.dataclass(frozen=True)
 class MacroSlot:
     unit: str
@@ -59,7 +71,13 @@ class MacroProgram:
 
 
 def compile_macro(graph: CellGraph) -> MacroProgram:
-    """ASAP list scheduling of the cell DAG onto the unit pools."""
+    """ASAP list scheduling of the cell DAG onto the unit pools. Raises
+    :class:`UnsupportedOpError` for an op no unit executes."""
+    for op in graph.ops:
+        if op.kind in UNSUPPORTED:
+            raise UnsupportedOpError(
+                f"{graph.name}: op {op.name!r} ({op.kind}) "
+                f"{UNSUPPORTED[op.kind]}")
     # dependency levels
     level: dict[str, int] = {}
     for op in graph.ops:

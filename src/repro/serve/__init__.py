@@ -15,8 +15,10 @@ engine replicas (load-aware via ``simulate_admission``) and simulates
 fleet-wide SLO attainment; ``scheduler`` owns request admission and
 slot/page-granular cache reuse; ``paging`` owns the fixed-size
 token-page pool (free list + dense page table + refcounted prefix
-trie) behind the paged cache. See docs/serving.md for the end-to-end
-tour.
+trie) behind the paged cache; ``transducer`` serves a streaming RNN-T
+chunk by chunk (``rnnt_serve_frames``: encoder layers through the frame
+server, then one greedy-decode program a chunk). See docs/serving.md
+for the end-to-end tour.
 """
 from .config import EngineConfig
 from .disagg import (
@@ -47,6 +49,7 @@ from .speculative import (
     generate_speculative,
     serve_continuous_speculative,
 )
+from .transducer import init_rnnt_state, rnnt_serve_frames
 from .scheduler import (
     Request,
     SlotScheduler,
@@ -65,7 +68,7 @@ from .scheduler import (
 __all__ = [
     "EngineConfig", "ServeResult", "bucket_len",
     "generate", "rnn_serve_frames", "serve_continuous",
-    "shard_cell_params",
+    "shard_cell_params", "init_rnnt_state", "rnnt_serve_frames",
     "DecodeTier", "PageHandoff", "PrefillTier", "serve_disaggregated",
     "POLICIES", "Router", "RouterResult", "make_arrival_trace", "route",
     "simulate_replicas",

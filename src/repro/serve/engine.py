@@ -811,14 +811,6 @@ _STEP_CACHE_SIZE = 32
 _step_cache: collections.OrderedDict = collections.OrderedDict()
 
 
-def _graph_key(graph: CellGraph) -> tuple:
-    """What ``cell_apply`` reads of ``graph``, hashable (``next_state``
-    is a dict)."""
-    return (graph.name, graph.input_dim, graph.hidden_dim, graph.ops,
-            graph.state_vars, tuple(sorted(graph.next_state.items())),
-            graph.output)
-
-
 def _frame_step_for(graph: CellGraph) -> tuple[Any, set]:
     """The cached jitted step of ``graph`` and the argument signatures
     it has run with, built by :func:`make_frame_step` on a miss.
@@ -831,21 +823,29 @@ def _frame_step_for(graph: CellGraph) -> tuple[Any, set]:
     from repro.core.csb_linear import _active_model_mesh
     from repro.kernels import ops
 
-    key = (_graph_key(graph), cell_apply, _active_model_mesh(),
+    key = (graph.key, cell_apply, _active_model_mesh(),
            ops.default_interpret())
-    entry = _step_cache.get(key)
-    hit = entry is not None
-    if hit:
-        _step_cache.move_to_end(key)
-    else:
-        entry = _step_cache[key] = (make_frame_step(graph), set())
-        if len(_step_cache) > _STEP_CACHE_SIZE:
-            _step_cache.popitem(last=False)
+    entry, hit = cached_program(key, lambda: make_frame_step(graph))
     reg = obs_metrics.get()
     if reg is not None:
         reg.counter("serve/frames/step_cache/"
                     + ("hit" if hit else "miss")).inc()
     return entry
+
+
+def cached_program(key, build) -> tuple[tuple[Any, set], bool]:
+    """The step cache's entry for ``key`` — the program ``build()``
+    made on a miss and the argument signatures it has run with — and
+    whether it was a hit."""
+    entry = _step_cache.get(key)
+    hit = entry is not None
+    if hit:
+        _step_cache.move_to_end(key)
+    else:
+        entry = _step_cache[key] = (build(), set())
+        if len(_step_cache) > _STEP_CACHE_SIZE:
+            _step_cache.popitem(last=False)
+    return entry, hit
 
 
 def _arg_signature(tree: PyTree) -> tuple:

@@ -12,14 +12,14 @@ from repro.core import (
 )
 
 
-@pytest.mark.parametrize("kind", ["lstm", "gru", "lstmp", "ligru"])
+@pytest.mark.parametrize("kind", ["lstm", "gru", "lstmp", "ligru", "lnlstmp"])
 def test_cell_shapes_finite(kind, rng):
     cell = make_cell(kind, 12, 24, proj_dim=16)
     params = init_params(cell, jax.random.PRNGKey(0))
     xs = jnp.asarray(rng.normal(size=(5, 2, 12)).astype(np.float32))
     ys, st = jax.jit(lambda p, x: rnn_scan(cell, p, x))(params, xs)
     assert np.isfinite(np.asarray(ys)).all()
-    out_dim = 16 if kind == "lstmp" else 24
+    out_dim = 16 if kind in ("lstmp", "lnlstmp") else 24
     assert ys.shape == (5, 2, out_dim)
 
 

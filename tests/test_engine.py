@@ -4,7 +4,7 @@ import pytest
 
 from repro.cells import make_cell
 from repro.core import CSBMatrix, CSBSpec, csb_masks, csb_project
-from repro.engine.isa import compile_macro
+from repro.engine.isa import UnsupportedOpError, compile_macro
 from repro.engine.schedule import (
     greedy_schedule, no_sharing_schedule, smt_schedule,
 )
@@ -42,6 +42,13 @@ def test_macro_compile_all_cells():
         for pool in pools:
             need = sum(counts.get(u, 0) for u in pool) / len(pool)
             assert need <= n_mvm + 1, (kind, pool, need, counts)
+
+
+def test_macro_refuses_layernorm():
+    """The datapath of Fig. 8 has no unit that reduces across a vector,
+    so a layer-normalised cell is refused by name, not mis-scheduled."""
+    with pytest.raises(UnsupportedOpError, match="ln_i.*layernorm"):
+        compile_macro(make_cell("lnlstmp", 16, 32, proj_dim=8))
 
 
 def test_macro_respects_dependencies():

@@ -10,7 +10,10 @@ here what it would refuse on the chip (block shapes that break the
   size the serving path uses (32, 64, 128);
 * the frame server's jitted SR1 frame step, ``cell_apply`` over
   ``PaddedCSB`` weights, under the names a profile finds it by;
-* ``csb_matvec_sharded`` on a 4-device ("data", "model") mesh.
+* ``csb_matvec_sharded`` on a 4-device ("data", "model") mesh;
+* at the widths of He et al. 2019's RNN-T (LN-LSTMP 2048 cells,
+  projection 640, 4,096 outputs, 64 streams): the frame step of the
+  encoder layer above the time reduction, and the greedy-decode program.
 
 The topology is described inside a module fixture, never at import: one
 process at a time may load the TPU library, and every pytest-xdist
@@ -139,6 +142,49 @@ def test_sr1_frame_step_compiles(one_chip, monkeypatch, layer):
     kernels = [line for line in hlo.splitlines()
                if 'custom_call_target="tpu_custom_call"' in line]
     assert kernels and all(
+        line.lstrip().startswith("%csb_mvm_pallas") for line in kernels)
+
+
+def test_rnnt_step_and_decode_compile(one_chip, monkeypatch):
+    from repro.models import transducer as T
+    from repro.serve.transducer import _decode_program
+
+    monkeypatch.setattr(ops, "default_interpret", lambda: False)
+    model = T.make_transducer(320, 2048, 640, encoder_layers=8,
+                              reduce_after=2, prediction_layers=2,
+                              vocab=4096, embed_dim=128, joint_dim=640)
+    streams = 64
+
+    def sds(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def cell(graph):
+        return ({name: (_csb(shape, 128, one_chip) if len(shape) == 2
+                        else sds(shape))
+                 for name, shape in graph.weight_shapes().items()},
+                jax.tree.map(lambda a: sds(a.shape, a.dtype),
+                             init_state(graph, (streams,))))
+
+    graph = model.encoder[model.reduce_after]       # 1,280 inputs
+    params, state = cell(graph)
+    hlo = make_frame_step(graph).lower(
+        params, state, sds((streams, 1280))).compile().as_text()
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 9
+    pred = [cell(g) for g in model.prediction]
+    params = {"encoder": [], "prediction": [p for p, _ in pred],
+              "embed": sds((4096, 128)),
+              "joint": {"W_e": sds((640, 640)), "W_p": sds((640, 640)),
+                        "b": sds((640,)), "W_out": sds((4096, 640)),
+                        "b_out": sds((4096,))}}
+    state = {"label": sds((streams,), jnp.int32),
+             "pred": [s for _, s in pred]}
+    hlo = _decode_program(model).lower(
+        params, sds((4, streams, 640)), state).compile().as_text()
+    assert hlo.startswith("HloModule jit_rnnt_decode,")
+    # one prediction step (2 layers of 9 products) in the program
+    kernels = [line for line in hlo.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(kernels) == 18 and all(
         line.lstrip().startswith("%csb_mvm_pallas") for line in kernels)
 
 
