@@ -1,7 +1,8 @@
 """CSB-MVM Pallas kernel accounting (replaces paper Fig. 11's FPGA
 resource table with the TPU-relevant quantities): VMEM working set per
-BlockSpec tile, padded-vs-true FLOPs across block sizes / pruning rates,
-and interpret-mode allclose latency vs the jnp oracle.
+grid step at the tiling ``csb_matvec`` picks, padded-vs-true FLOPs
+across block sizes / pruning rates, and interpret-mode allclose latency
+vs the jnp oracle.
 
 Also benches the Pallas paged-attention decode kernel
 (``kernel/paged_attn/decode``, GATED — see benchmarks/diff.py) against
@@ -18,20 +19,10 @@ import numpy as np
 
 from repro.core import CSBSpec, csb_masks, csb_project, padded_csb_from_dense
 from repro.kernels import paged_attn_decode
-from repro.kernels.ops import csb_matvec
+from repro.kernels.ops import csb_matvec, csb_tiling, csb_vmem_bytes
 from repro.kernels.ref import csb_mvm_ref
 from repro.models.layers import paged_gather
 from .common import emit, synthetic_rnn_weight, timed
-
-
-def vmem_bytes(p, batch_tile: int, group: int) -> int:
-    """Working set one grid step stages into VMEM."""
-    bm, bn = p.block
-    pm, pn = p.pm, p.pn
-    x_tile = batch_tile * group * bn * 4
-    w_tile = group * (pm * pn * p.vals.dtype.itemsize + pm * 4 + pn * 4 + 8)
-    o_tile = batch_tile * bm * 4
-    return x_tile + w_tile + o_tile
 
 
 def _paged_attn_rows() -> None:
@@ -91,7 +82,8 @@ def run() -> None:
                 row_mask=np.asarray(rm), col_mask=np.asarray(cm))
             pad_ratio = p.padded_flops_per_mvm() / max(
                 p.true_flops_per_mvm(), 1)
-            vb = vmem_bytes(p, batch_tile=8, group=1)
+            tiling = csb_tiling(x.shape[0], p.grid, p.block, p.pm, p.pn)
+            vb = csb_vmem_bytes(*tiling, p.block, p.pm, p.pn)
             y_ref, t_ref = timed(lambda: csb_mvm_ref(p, x))
             y_ker, t_ker = timed(lambda: csb_matvec(p, x), iters=5,
                                  reduce="min")

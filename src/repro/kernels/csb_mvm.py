@@ -36,8 +36,17 @@ handed, masking pad lanes so padded FLOPs never corrupt results.
 
 Grid: ``(batch_tiles, Br, Bc/G)`` — the last axis accumulates into a
 VMEM scratch tile (minor-most, so the accumulator stays resident) and
-stores the output block once, on the final column step. All three dots
-run at ``Precision.HIGHEST`` so the kernel is an fp32 matvec on the MXU.
+stores the output block once, on the final column step. A step runs
+its G blocks in a loop. All three dots run at ``Precision.HIGHEST`` so
+the kernel is an fp32 matvec on the MXU.
+
+The tiling is the caller's: ``ops.csb_tiling`` picks it from the shapes.
+On a TPU v5e a block's three dependent dots cost about 0.4-0.5 us at 8
+batch rows and hardly more at 64, far more than their MXU work: at 8
+rows a tile the kernel's time was its count of (batch tile, block)
+visits. So the chosen tiling takes the whole batch and a whole
+block-row (G = Bc) a step where VMEM allows: one batch tile, grid
+``(1, Br, 1)``, each block visited and its values read once a call.
 """
 from __future__ import annotations
 
@@ -65,26 +74,28 @@ def default_interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
-def _kernel(x_ref, vals_ref, ridx_ref, cidx_ref, o_ref, acc_ref, *,
-            group: int):
+def _kernel(x_ref, vals_ref, ridx_ref, cidx_ref, o_ref, acc_ref):
     """One grid step: TB batch rows x one block-row x G blocks.
 
-    The block-column reduction (grid axis 2) accumulates into the VMEM
-    scratch ``acc_ref`` — persistent across grid steps that revisit the
-    same output tile — and ``o_ref`` is stored exactly once, on the
-    final column step. The output ref is never read, so the kernel does
-    not rely on sequential-grid read-modify-write semantics."""
+    The G blocks run as a loop (``fori_loop``, so the body and its
+    compile time do not grow with G), each adding its product into the
+    accumulator in block-column order. The block-column reduction
+    (grid axis 2) accumulates into the VMEM scratch ``acc_ref`` —
+    persistent across grid steps that revisit the same output tile — and
+    ``o_ref`` is stored exactly once, on the final column step. The
+    output ref is never read, so the kernel does not rely on
+    sequential-grid read-modify-write semantics."""
     jc = pl.program_id(2)
 
     @pl.when(jc == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
+    group, _, bn = x_ref.shape
     pm, pn = vals_ref.shape[-2:]
-    bn = x_ref.shape[-1]
     bm = o_ref.shape[-1]
-    acc = acc_ref[...]
-    for g in range(group):
+
+    def block(g, acc):
         # ---- gather input neurons by ColIdx (one-hot matmul on MXU) ----
         xs = x_ref[g].astype(jnp.float32)                        # (TB, bn)
         coh = jnp.where(
@@ -103,10 +114,11 @@ def _kernel(x_ref, vals_ref, ridx_ref, cidx_ref, o_ref, acc_ref, *,
         roh = jnp.where(
             jax.lax.broadcasted_iota(jnp.int32, (bm, pm), 0)
             == ridx_ref[0, g], 1.0, 0.0)                         # (bm, Pm)
-        acc = acc + jax.lax.dot_general(
+        return acc + jax.lax.dot_general(
             yk, roh, _NT, precision=_HI,
             preferred_element_type=jnp.float32)                  # (TB, bm)
-    acc_ref[...] = acc
+
+    acc_ref[...] = jax.lax.fori_loop(0, group, block, acc_ref[...])
 
     @pl.when(jc == pl.num_programs(2) - 1)
     def _store():
@@ -162,7 +174,7 @@ def csb_mvm_pallas(
     cidx4 = _live_idx(col_idx, n).reshape(br, bc, 1, pn)
 
     out = pl.pallas_call(
-        functools.partial(_kernel, group=group),
+        _kernel,
         grid=(b // batch_tile, br, bc // group),
         in_specs=[
             pl.BlockSpec((group, batch_tile, bn),

@@ -7,7 +7,10 @@ here what it would refuse on the chip (block shapes that break the
 153->1024, projection 512, prune 1-1/13):
 
 * the CSB kernel, ``csb_mvm_pallas(interpret=False)``, at every block
-  size the serving path uses (32, 64, 128);
+  size the serving path uses (32, 64, 128), and at the tiling
+  ``ops.csb_tiling`` chooses for the benchmark's batches (the whole
+  batch and a whole block-row a grid step) and for a batch too large to
+  stage whole;
 * the frame server's jitted SR1 frame step, ``cell_apply`` over
   ``PaddedCSB`` weights, under the names a profile finds it by;
 * ``csb_matvec_sharded`` on a 4-device ("data", "model") mesh;
@@ -105,6 +108,26 @@ def test_csb_kernel_compiles_at_sr1_widths(one_chip, block):
             p.vals, p.row_idx, p.col_idx, p.m, p.n, x, grid=p.grid,
             block=p.block, batch_tile=8, group=1, interpret=False).compile()
         assert "tpu_custom_call" in compiled.as_text(), shape
+
+
+@pytest.mark.parametrize("shape,streams", [
+    ((1024, 153), 256), ((1024, 512), 256), ((512, 1024), 256),   # SR1
+    ((2048, 320), 64), ((2048, 640), 64), ((2048, 1280), 64),     # RNN-T
+    ((640, 2048), 64), ((2048, 128), 64),
+    ((2048, 4096), 4096),     # past the VMEM budget: split by csb_tiling
+])
+def test_csb_kernel_compiles_at_chosen_tiling(one_chip, shape, streams):
+    p = _csb(shape, 128, one_chip)
+    tb, group = ops.csb_tiling(streams, p.grid, p.block, p.pm, p.pn)
+    if streams <= 256:
+        assert (tb, group) == (streams, p.grid[1])
+    x = jax.ShapeDtypeStruct((streams, p.grid[1] * 128), jnp.float32,
+                             sharding=one_chip)
+    compiled = csb_mvm_pallas.lower(
+        p.vals, p.row_idx, p.col_idx, p.m, p.n, x, grid=p.grid,
+        block=p.block, batch_tile=tb, group=group,
+        interpret=False).compile()
+    assert "tpu_custom_call" in compiled.as_text(), shape
 
 
 def test_csb_kernel_compiles_bf16_grouped(one_chip):
