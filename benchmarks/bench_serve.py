@@ -38,13 +38,6 @@ noise-robust min-of-N statistic:
       derived = the realtime criterion check (<500 us is only
       meaningful on real hardware; CPU-interpret numbers gate only
       against themselves).
-  serve/frames/p99_us_per_frame — tail frame latency from a separate
-      per-frame-blocking pass (the realtime criterion cares about the
-      worst frame; blocking serializes the pipeline, so it must not
-      pollute the gated mean row). Gated by diff.py's ABSOLUTE
-      realtime budget (--realtime-budget-us, default 500us normalized)
-      rather than the relative rule: a p99 drifting within budget is
-      fine, one crossing the frame deadline is a failure.
 
 Informational rows (never gate: us_per_call = 0): achieved slot
 occupancy, the scheduler's prefill/decode-step counts, the paged
@@ -281,23 +274,10 @@ def run() -> None:
         else:
             csb_params[k] = w
     frames = jax.random.normal(jax.random.PRNGKey(3), (24, 4, 64))
-    best_us = float("inf")
-    frame_us = None
-    fcfg = EngineConfig(frame_warmup=1, collect_frame_times=True)
-    for _ in range(3):
-        _, _, us, ft = rnn_serve_frames(cell, csb_params, frames,
-                                        config=fcfg)
-        if us < best_us:
-            best_us, frame_us = us, ft
+    best_us = min(rnn_serve_frames(cell, csb_params, frames)[2]
+                  for _ in range(3))
     emit("serve/frames/us_per_frame", best_us,
          f"realtime_500us={best_us < 500.0}")
-    # tail latency (per-frame-blocking pass). The name has no "/us_per"
-    # segment so the relative /us_per gate never fires on it; instead
-    # diff.py's --realtime-row matches the "p99" and holds the value to
-    # the absolute --realtime-budget-us frame deadline.
-    p99 = float(np.percentile(frame_us, 99))
-    emit("serve/frames/p99_us_per_frame", p99,
-         f"realtime_500us={p99 < 500.0}")
 
     # -- observability lane (informational; tracing ON for these only) -----
     # Jit caches are warm from the gated runs above, so the traced run

@@ -48,7 +48,7 @@ import time
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 ROOT = os.path.dirname(os.path.abspath(__file__))
 sys.path.insert(0, os.path.join(ROOT, "src"))
@@ -59,6 +59,7 @@ from repro.configs import PAPER_MODELS, get_config  # noqa: E402
 from repro.core import (  # noqa: E402
     CSBSpec, PaddedCSB, csb_masks, csb_project, padded_csb_from_dense,
 )
+from repro.dist import Rules, use_rules  # noqa: E402
 from repro.kernels.csb_mvm import default_interpret  # noqa: E402
 from repro.kernels.ops import csb_matvec  # noqa: E402
 from repro.kernels.ref import csb_mvm_ref  # noqa: E402
@@ -66,6 +67,7 @@ from repro.launch.compile_cache import enable_compile_cache  # noqa: E402
 from repro.models import lm as LM  # noqa: E402
 from repro.serve import (  # noqa: E402
     EngineConfig, Request, generate, rnn_serve_frames, serve_continuous,
+    shard_cell_params,
 )
 
 SEED = 0
@@ -181,18 +183,13 @@ def frames_phase(layers, key) -> None:
     got = want = xs
     for li, (graph, dense, csb) in enumerate(layers):
         t0 = time.perf_counter()
-        got, _, us, frame_us = rnn_serve_frames(
-            graph, csb, got, config=EngineConfig(collect_frame_times=True))
+        got, _, us = rnn_serve_frames(graph, csb, got)
         wall = time.perf_counter() - t0
         want = lstmp_reference(dense, want)
         err = float(jnp.abs(got - want).max())
         say("frames", layer=li + 1, shape=tuple(got.shape),
-            compile_warmup_s=round(
-                wall - (FRAMES * us + frame_us.sum()) / 1e6, 3),
-            us_per_frame=round(us, 2),
-            p50_frame_us=round(float(np.percentile(frame_us, 50)), 2),
-            p99_frame_us=round(float(np.percentile(frame_us, 99)), 2),
-            max_err=err, tol=FRAME_TOL)
+            compile_warmup_s=round(wall - FRAMES * us / 1e6, 3),
+            us_per_frame=round(us, 2), max_err=err, tol=FRAME_TOL)
         if not bool(jnp.isfinite(got).all()) or not err <= FRAME_TOL:
             fail(f"SR1 layer {li + 1} frames off the dense reference "
                  f"by {err} (tol {FRAME_TOL})")
@@ -335,9 +332,12 @@ def four_chip_phase(key) -> None:
     layers = sr1_layers(k_sr1)
     one = four = jax.random.normal(
         k_x, (FRAMES, STREAMS, layers[0][0].input_dim))
+    four = jax.device_put(four, NamedSharding(mesh, P()))
     for li, (graph, _, csb) in enumerate(layers):
         one, _, us1 = rnn_serve_frames(graph, csb, one)
-        four, _, us4 = rnn_serve_frames(graph, csb, four, mesh=mesh)
+        sharded = shard_cell_params(csb, mesh)
+        with use_rules(Rules({}, mesh=mesh)):
+            four, _, us4 = rnn_serve_frames(graph, sharded, four)
         diff = float(jnp.abs(one - four).max())
         say("frames-4chip", layer=li + 1, mesh="1x4",
             us_per_frame_1chip=round(us1, 2),

@@ -51,13 +51,15 @@ import time
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.cells import init_params as cell_init, make_cell
 from repro.core import CSBSpec, csb_masks, csb_project, padded_csb_from_dense
+from repro.dist import Rules, use_rules
 from repro.models import ModelConfig, init_params
 from repro.serve import (
     EngineConfig, Request, generate, rnn_serve_frames, serve_continuous,
+    shard_cell_params,
 )
 
 mesh = None
@@ -199,7 +201,14 @@ for k, w in wparams.items():
         csb_params[k] = w
 
 frames = jax.random.normal(jax.random.PRNGKey(3), (32, 4, 64))
-outs, _, us = rnn_serve_frames(cell, csb_params, frames, mesh=mesh)
+if mesh is None:
+    outs, _, us = rnn_serve_frames(cell, csb_params, frames)
+else:
+    # shard once: weights over "model", the stream batch over "data"
+    sharded = shard_cell_params(csb_params, mesh)
+    frames = jax.device_put(frames, NamedSharding(mesh, P(None, "data")))
+    with use_rules(Rules({}, mesh=mesh)):
+        outs, _, us = rnn_serve_frames(cell, sharded, frames)
 where = "sharded mesh" if mesh is not None else "single device"
 print(f"\nCSB-RNN frames ({where}): {frames.shape[0]} frames x batch "
       f"{frames.shape[1]} -> {us:.1f} us/frame "

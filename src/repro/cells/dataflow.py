@@ -19,13 +19,12 @@ consumers:
 from __future__ import annotations
 
 import dataclasses
-from typing import Callable
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.csb_format import PaddedCSB, ShardedCSB
+from repro.core.csb_format import PaddedCSB
 
 KINDS = ("input", "mvm", "bias", "add", "mul",
          "sigmoid", "tanh", "relu", "one_minus", "layernorm")
@@ -166,29 +165,16 @@ class GraphBuilder:
 # Executor
 # ---------------------------------------------------------------------------
 
-def _apply_mvm(w, x: jax.Array) -> jax.Array:
-    if isinstance(w, ShardedCSB):
-        from repro.core.csb_linear import _active_model_mesh
-        from repro.kernels.csb_sharded import csb_matvec_sharded
-        mesh = _active_model_mesh()
-        if mesh is None:
-            raise ValueError(
-                "ShardedCSB cell weight needs an active use_rules scope "
-                "whose mesh has a non-trivial 'model' axis")
-        return csb_matvec_sharded(w, x, mesh=mesh).astype(x.dtype)
-    if isinstance(w, PaddedCSB):
-        from repro.kernels.ops import csb_matvec
-        return csb_matvec(w, x).astype(x.dtype)
-    return jnp.einsum("...i,oi->...o", x, w.astype(x.dtype))
-
-
 def cell_apply(
     graph: CellGraph,
     params: dict[str, jax.Array | PaddedCSB],
     x: jax.Array,
     state: dict[str, jax.Array],
 ) -> tuple[jax.Array, dict[str, jax.Array]]:
-    """One cell step. x: (..., input_dim); state vars: (..., hidden_dim)."""
+    """One cell step. x: (..., input_dim); state vars: (..., hidden_dim).
+    Each mvm runs through ``kernels.ops.matvec``."""
+    from repro.kernels.ops import matvec
+
     env: dict[str, jax.Array] = {"x": x, **state}
     for op in graph.ops:
         if op.kind == "input":
@@ -196,7 +182,7 @@ def cell_apply(
             continue
         a = env[op.inputs[0]]
         if op.kind == "mvm":
-            env[op.name] = _apply_mvm(params[op.name], a)
+            env[op.name] = matvec(params[op.name], a)
         elif op.kind == "bias":
             env[op.name] = a + params[op.name].astype(a.dtype)
         elif op.kind == "add":

@@ -30,18 +30,6 @@ from .pruning import CSBSpec, csb_masks, csb_project
 PyTree = Any
 
 
-def _active_model_mesh(axis: str = "model"):
-    """The mesh from the active ``dist`` Rules when its ``axis`` is
-    non-trivial — the signal that the sharded CSB path should run.
-    None on single-device paths, so tests/CPU stay on the local kernel."""
-    from repro.dist.api import current_rules
-    rules = current_rules()
-    mesh = getattr(rules, "mesh", None)
-    if mesh is None or axis not in tuple(mesh.axis_names):
-        return None
-    return mesh if mesh.shape[axis] > 1 else None
-
-
 @dataclasses.dataclass
 class CSBLinear:
     """Stateful wrapper around one projection weight."""
@@ -82,6 +70,7 @@ class CSBLinear:
         return self._shards[key]
 
     def __call__(self, x: jax.Array) -> jax.Array:
+        from repro.kernels.ops import _active_model_mesh, matvec
         if self.mode == "dense":
             w = self._w_oi()
         elif self.mode == "masked":
@@ -89,16 +78,11 @@ class CSBLinear:
         elif self.mode == "csb":
             assert self._packed is not None, "call freeze() first"
             mesh = _active_model_mesh()
-            if mesh is not None:
-                from repro.kernels.csb_sharded import csb_matvec_sharded
-                _, sharded = self.shard_for_mesh(mesh)
-                return csb_matvec_sharded(
-                    sharded, x, mesh=mesh).astype(x.dtype)
-            from repro.kernels.ops import csb_matvec
-            return csb_matvec(self._packed, x).astype(x.dtype)
+            w = (self._packed if mesh is None
+                 else self.shard_for_mesh(mesh)[1])
         else:  # pragma: no cover
             raise ValueError(self.mode)
-        return jnp.einsum("...i,oi->...o", x, w.astype(x.dtype))
+        return matvec(w, x)
 
     def compression(self) -> float:
         if self._packed is None:

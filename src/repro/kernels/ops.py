@@ -1,4 +1,10 @@
-"""Public jit'd wrappers around the Pallas CSB kernels.
+"""Public jit'd wrappers around the Pallas CSB kernels, and the one
+place that picks the program of a product ``x @ W^T``.
+
+``matvec(w, x)`` runs a dense ``w`` as an einsum, a ``PaddedCSB`` through
+the local kernel and a ``ShardedCSB`` through the sharded kernel on the
+active model mesh. ``trace_context()`` is what a program that calls it
+reads besides its arguments, for caches of jitted programs to key on.
 
 ``csb_matvec(p, x)`` accepts any leading batch shape (including none — a
 single vector, the paper's MVM case), pads batch/feature dims to the
@@ -19,7 +25,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from repro.core.csb_format import PaddedCSB
+from repro.core.csb_format import PaddedCSB, ShardedCSB
 from repro.obs import metrics as obs_metrics
 from .csb_mvm import csb_mvm_pallas, default_interpret
 
@@ -127,3 +133,37 @@ def csb_matvec(
         batch_tile, group = batch_tile or tb, group or g
     y = _run(p, x2, batch_tile, group, interpret)
     return y.reshape(*batch_shape, p.shape[0])
+
+
+def _active_model_mesh(axis: str = "model"):
+    """The mesh of the active ``dist`` Rules when its ``axis`` is
+    non-trivial: the mesh a ``ShardedCSB`` product runs on. None on one
+    device."""
+    from repro.dist.api import current_rules
+    mesh = getattr(current_rules(), "mesh", None)
+    if mesh is None or axis not in tuple(mesh.axis_names):
+        return None
+    return mesh if mesh.shape[axis] > 1 else None
+
+
+def trace_context() -> tuple:
+    """What a traced :func:`matvec` reads besides its arguments, hashable:
+    the active model mesh and the kernel's interpret mode."""
+    return _active_model_mesh(), default_interpret()
+
+
+def matvec(w, x: jax.Array) -> jax.Array:
+    """y = x @ w^T at ``x``'s dtype; ``w`` is a dense (out, in) array, a
+    ``PaddedCSB`` (the local kernel) or a ``ShardedCSB`` (the sharded
+    kernel, which needs an active model mesh)."""
+    if isinstance(w, ShardedCSB):
+        from .csb_sharded import csb_matvec_sharded
+        mesh = _active_model_mesh()
+        if mesh is None:
+            raise ValueError(
+                "ShardedCSB weight needs an active use_rules scope "
+                "whose mesh has a non-trivial 'model' axis")
+        return csb_matvec_sharded(w, x, mesh=mesh).astype(x.dtype)
+    if isinstance(w, PaddedCSB):
+        return csb_matvec(w, x).astype(x.dtype)
+    return jnp.einsum("...i,oi->...o", x, w.astype(x.dtype))

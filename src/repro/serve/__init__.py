@@ -1,14 +1,14 @@
 """repro.serve — batched + continuous-batching inference loops.
 
-``config`` owns the unified :class:`EngineConfig` every entry point
-consumes; ``engine`` owns
-the device loops (fixed-batch ``generate``, slot-based
-``serve_continuous`` — contiguous or paged cache, pow2 prompt-bucketed
-prefill, copy-on-write prefix sharing — and frame-by-frame
-``rnn_serve_frames``), all of which run sharded under the ``dist``
-rules when a mesh is supplied; ``disagg`` splits the engine into a
-prefill tier and a fixed-slot decode tier joined by explicit
-:class:`PageHandoff` remaps; ``speculative`` drafts with a CSB-pruned
+``config`` owns the unified :class:`EngineConfig` the LM entry points
+consume; ``engine`` owns the device loops: fixed-batch ``generate`` and
+slot-based ``serve_continuous`` (contiguous or paged cache, pow2
+prompt-bucketed prefill, copy-on-write prefix sharing), which run
+sharded under the ``dist`` rules when a mesh is supplied, and
+frame-by-frame ``rnn_serve_frames``, which runs sharded weights from
+``shard_cell_params`` inside a ``use_rules`` mesh scope; ``disagg``
+splits the engine into a prefill tier and a fixed-slot decode tier
+joined by explicit :class:`PageHandoff` remaps; ``speculative`` drafts with a CSB-pruned
 copy of the target and verifies ``spec_k``-token runs in one
 multi-position decode step; ``router`` places a request trace over N
 engine replicas (load-aware via ``simulate_admission``) and simulates

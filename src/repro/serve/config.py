@@ -5,8 +5,9 @@ paging, bucketing, prefix sharing, the Pallas decode kernel, ...) while
 ``generate`` took a separate three-field ``ServeConfig`` — the same
 engine, two half-configs. :class:`EngineConfig` folds all of it into a
 single validated frozen dataclass consumed by ``generate``,
-``serve_continuous``, ``rnn_serve_frames``, ``serve_disaggregated`` and
-the multi-replica :class:`repro.serve.router.Router`.
+``serve_continuous``, ``serve_disaggregated`` and the multi-replica
+:class:`repro.serve.router.Router`. The frame server,
+``rnn_serve_frames``, takes none.
 
 Cross-field constraints live in ``__post_init__`` so an invalid
 combination fails at construction, not three layers deep in the engine:
@@ -55,12 +56,6 @@ class EngineConfig:
     Prefill:
       ``bucket_prompts`` — pow2 prompt buckets (None: on when paged,
                            auto-off for SSD/hybrid mixers).
-
-    Frame serving (``rnn_serve_frames``):
-      ``frame_warmup``         — steps before timing, when the step is
-                                 fresh (its first call with these
-                                 argument shapes and shardings).
-      ``collect_frame_times``  — per-frame blocking latency pass.
     """
 
     # generation
@@ -81,9 +76,6 @@ class EngineConfig:
     draft_prune_rate: float = 0.5
     # prefill
     bucket_prompts: bool | None = None
-    # frame serving
-    frame_warmup: int = 2
-    collect_frame_times: bool = False
 
     def __post_init__(self):
         if self.max_new_tokens < 1:
@@ -96,8 +88,6 @@ class EngineConfig:
             raise ValueError("n_slots must be >= 1")
         if self.page_size < 1:
             raise ValueError("page_size must be >= 1")
-        if self.frame_warmup < 0:
-            raise ValueError("frame_warmup must be >= 0")
         if not self.paged:
             # every paged-only knob must fail loudly instead of being
             # silently ignored by the contiguous engine

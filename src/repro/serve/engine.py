@@ -18,15 +18,17 @@ weights; returns per-frame outputs and the wall-clock per frame so the
 faster-than-realtime criterion (<500 us/frame for speech) can be checked
 on real hardware.
 
-All three run under the ``dist`` sharding rules: pass ``mesh=`` (or call
-inside a ``use_rules`` scope whose Rules carry a mesh) and parameters
-are placed via ``param_specs``/``csb_shard_specs`` on the "model" axis
-(CSB weights route through ``csb_matvec_sharded``), while the decode
-cache and token batch shard over the "data" axes via
-``cache_specs``/``batch_specs`` — the data axes act as a replica set
-for continuous batching, each replica carrying its share of the slots.
-Without a mesh everything degrades to the single-device paths the CPU
-tests use.
+``generate`` and ``serve_continuous`` run under the ``dist`` sharding
+rules: pass ``mesh=`` (or call inside a ``use_rules`` scope whose Rules
+carry a mesh) and parameters are placed via
+``param_specs``/``csb_shard_specs`` on the "model" axis (CSB weights
+route through ``csb_matvec_sharded``), while the decode cache and token
+batch shard over the "data" axes via ``cache_specs``/``batch_specs`` —
+the data axes act as a replica set for continuous batching, each
+replica carrying its share of the slots. Without a mesh everything
+degrades to the single-device paths the CPU tests use.
+``rnn_serve_frames`` takes weights sharded once by
+``shard_cell_params`` and runs them inside a ``use_rules`` mesh scope.
 """
 from __future__ import annotations
 
@@ -778,9 +780,9 @@ def serve_continuous(params, cfg: ModelConfig, requests: list[Request],
 def shard_cell_params(params: dict, mesh, axis_name: str = "model") -> dict:
     """Cycle-balance every ``PaddedCSB`` cell weight over
     ``mesh[axis_name]`` (``dist.csb_partition``'s greedy planner) and
-    place the whole tree with ``csb_shard_specs`` — after this,
-    ``cell_apply`` under ``use_rules`` routes each MVM through
-    ``csb_matvec_sharded``."""
+    place the whole tree with ``csb_shard_specs``. Done once per model:
+    ``rnn_serve_frames`` called under ``use_rules(Rules({}, mesh=mesh))``
+    then runs each product through ``csb_matvec_sharded``."""
     from repro.core.csb_format import PaddedCSB
     from repro.dist.csb_partition import partition_padded
 
@@ -817,14 +819,11 @@ def _frame_step_for(graph: CellGraph) -> tuple[Any, set]:
 
     The key is what the step's trace reads besides its arguments: the
     graph's structure, the ``cell_apply`` it calls (a module global,
-    looked up when it traces), the active model mesh (``ShardedCSB``
-    weights route by it) and the kernel's interpret mode. jit's own
-    cache keys the rest (shapes, dtypes, shardings)."""
-    from repro.core.csb_linear import _active_model_mesh
+    looked up when it traces) and ``kernels.ops.trace_context()``. jit's
+    own cache keys the rest (shapes, dtypes, shardings)."""
     from repro.kernels import ops
 
-    key = (graph.key, cell_apply, _active_model_mesh(),
-           ops.default_interpret())
+    key = (graph.key, cell_apply, ops.trace_context())
     entry, hit = cached_program(key, lambda: make_frame_step(graph))
     reg = obs_metrics.get()
     if reg is not None:
@@ -859,41 +858,20 @@ def _arg_signature(tree: PyTree) -> tuple:
 
 
 def rnn_serve_frames(graph: CellGraph, params: PyTree, frames,
-                     state: PyTree | None = None,
-                     warmup: int | None = None,
-                     *, config: EngineConfig | None = None, mesh=None,
-                     axis_name: str = "model",
-                     collect_frame_times: bool | None = None):
-    """frames: (T, B, in_dim). Weights may be dense, PaddedCSB, or (with
-    a mesh) ShardedCSB.
+                     state: PyTree | None = None):
+    """Serve ``frames`` (T, B, in_dim) through ``graph`` one jitted step a
+    frame. Returns (outputs (T, B, H), final state, us_per_frame).
 
-    ``config.frame_warmup`` / ``config.collect_frame_times`` are the
-    :class:`EngineConfig` homes of the two knobs; the positional
-    ``warmup`` and ``collect_frame_times`` arguments override them when
-    given explicitly (both default to the config).
+    Weights may be dense, ``PaddedCSB`` or ``ShardedCSB``; the last need
+    a model mesh: shard once with :func:`shard_cell_params`, place the
+    frames, and call inside ``use_rules(Rules({}, mesh=mesh))``.
 
-    The jitted step is built once per graph structure, model mesh and
-    kernel interpret mode and reused across calls (a small LRU). The
-    ``warmup`` steps run before timing only when the step is fresh to
-    these arguments: its first call with this tree structure and these
-    leaf shapes, dtypes and shardings of ``(params, state, frames)``,
-    i.e. when the step would trace, lower and compile. On later calls
-    nothing compiles and no warm-up step runs.
-
-    With ``mesh=`` (or an active Rules mesh with a non-trivial "model"
-    axis) the CSB weights are partitioned over the model axis and the
-    frame batch sharded over the data axes, so the per-frame latency is
-    measured on the sharded mesh — the paper's faster-than-realtime
-    number at multi-chip scale. Returns (outputs (T,B,H), final state,
-    us_per_frame).
-
-    ``collect_frame_times=True`` appends a 4th element: a ``(T,)``
-    numpy array of per-frame wall microseconds, each frame blocked to
-    completion before the next starts. Blocking serializes the device
-    pipeline, so the MEAN of these is pessimistic — the un-blocked
-    ``us_per_frame`` stays the throughput number; the per-frame vector
-    is for tail latency (p99) reporting, where realtime audio cares
-    about the worst frame, not the average.
+    The jitted step is built once per graph structure and
+    ``kernels.ops.trace_context()`` and reused across calls (a small
+    LRU). One warm-up step, on ``frames[0]``, runs before timing only
+    when the step is fresh to these arguments: its first call with this
+    tree structure and these leaf shapes, dtypes and shardings of
+    ``(params, state, frames)``, when it would trace, lower and compile.
 
     Each call opens a ``serve/frames/call`` span and one span per phase
     inside it (``prepare``, ``warmup``, ``dispatch``, ``sync``,
@@ -901,83 +879,26 @@ def rnn_serve_frames(graph: CellGraph, params: PyTree, frames,
     ring tracer is on or a JAX profiler session is active."""
     with obs_trace.span("serve/frames/call"):
         with obs_trace.span("serve/frames/prepare"):
-            fcfg = resolve_config(config, caller="rnn_serve_frames")
-            if warmup is None:
-                warmup = fcfg.frame_warmup
-            if collect_frame_times is None:
-                collect_frame_times = fcfg.collect_frame_times
-            mesh = _resolve_mesh(mesh)
-            rules = current_rules()
-            if mesh is not None:
-                if axis_name in tuple(mesh.axis_names) \
-                        and mesh.shape[axis_name] > 1:
-                    params = shard_cell_params(params, mesh, axis_name)
-                frames = jnp.asarray(frames)      # (T, B, in): B=dp
-                frames = jax.device_put(frames, NamedSharding(
-                    mesh, _dp_spec(mesh, frames.shape, batch_axis=1)))
-                if rules is None or rules.mesh is not mesh:
-                    rules = Rules({}, mesh=mesh)
-            if rules is None:
-                rules = Rules({})
-
             if state is None:
                 state = init_state(graph, frames.shape[1:-1], jnp.float32)
-
-            with use_rules(rules):
-                step, seen = _frame_step_for(graph)
+            step, seen = _frame_step_for(graph)
             sig = _arg_signature((params, state, frames))
-            fresh = sig not in seen
-
-        with use_rules(rules):
-            with obs_trace.span("serve/frames/warmup"):
-                # a fresh step's trace, lowering and compile; empty when
-                # the step has run with these arguments before
-                if fresh and warmup:
-                    for _ in range(warmup):
-                        y, _ = step(params, state, frames[0])
-                    y.block_until_ready()
+        with obs_trace.span("serve/frames/warmup"):
+            # a fresh step's trace, lowering and compile; empty when the
+            # step has run with these arguments before
+            if sig not in seen:
+                step(params, state, frames[0])[0].block_until_ready()
                 seen.add(sig)
-
-            outs = []
-            t0 = time.perf_counter()
-            st = state
-            with obs_trace.span("serve/frames/dispatch"):
-                for t in range(frames.shape[0]):
-                    y, st = step(params, st, frames[t])
-                    outs.append(y)
-            with obs_trace.span("serve/frames/sync"):
-                jax.block_until_ready(outs[-1])
-            dt = time.perf_counter() - t0
-
-            frame_us = None
-            if collect_frame_times:
-                # separate per-frame-blocking pass so the throughput
-                # number above is untouched by the serialization;
-                # per-frame spans and the realtime histogram
-                # (serve/frames/wall_us — the distribution the 500us
-                # budget judges) come from HERE, measured times
-                # recorded after the fact so tracing adds zero overhead
-                # inside the timed region
-                tr = obs_trace.get()
-                reg = obs_metrics.get()
-                times = np.empty(frames.shape[0])
-                st2 = state
-                for t in range(frames.shape[0]):
-                    f0 = time.perf_counter_ns()
-                    y2, st2 = step(params, st2, frames[t])
-                    jax.block_until_ready((y2, st2))
-                    dur = time.perf_counter_ns() - f0
-                    times[t] = dur / 1e3
-                    if tr is not None:
-                        tr.complete("serve/frame", f0, dur, track="frames",
-                                    args={"frame": t})
-                    if reg is not None:
-                        reg.histogram("serve/frames/wall_us").observe(
-                            dur / 1e3)
-                frame_us = times
+        outs = []
+        t0 = time.perf_counter()
+        st = state
+        with obs_trace.span("serve/frames/dispatch"):
+            for t in range(frames.shape[0]):
+                y, st = step(params, st, frames[t])
+                outs.append(y)
+        with obs_trace.span("serve/frames/sync"):
+            jax.block_until_ready(outs[-1])
+        dt = time.perf_counter() - t0
         with obs_trace.span("serve/frames/stack"):
             ys = jnp.stack(outs)
-    us_per_frame = dt / frames.shape[0] * 1e6
-    if collect_frame_times:
-        return ys, st, us_per_frame, frame_us
-    return ys, st, us_per_frame
+    return ys, st, dt / frames.shape[0] * 1e6

@@ -36,16 +36,14 @@ def init_rnnt_state(model: T.Transducer, batch: int) -> PyTree:
 
 def _decode_program(model: T.Transducer):
     """The jitted greedy decode of ``model``, built once per structure
-    and kernel mode (the frame server's step cache); its program is
-    ``jit_rnnt_decode`` in a profile."""
-    from repro.core.csb_linear import _active_model_mesh
+    and ``kernels.ops.trace_context()`` (the frame server's step cache);
+    its program is ``jit_rnnt_decode`` in a profile."""
     from repro.kernels import ops
 
     def rnnt_decode(params, enc, state):
         return T.greedy_decode(model, params, enc, state)
 
-    key = ("rnnt/decode", model.key, T.greedy_decode, _active_model_mesh(),
-           ops.default_interpret())
+    key = ("rnnt/decode", model.key, T.greedy_decode, ops.trace_context())
     (fn, _), _ = engine.cached_program(key, lambda: jax.jit(rnnt_decode))
     return fn
 

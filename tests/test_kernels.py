@@ -177,3 +177,24 @@ def test_empty_blocks(rng):
     np.testing.assert_allclose(np.asarray(y), np.asarray(x) @ z.T,
                                rtol=1e-5, atol=1e-5)
     np.testing.assert_allclose(np.asarray(densify(p)), z, atol=0)
+
+
+@pytest.mark.parametrize("weight", ["dense", "padded", "sharded_no_mesh"])
+def test_matvec_picks_the_program_by_weight(rng, weight):
+    """``ops.matvec`` runs a dense weight as an einsum, a ``PaddedCSB``
+    through the kernel, and refuses a ``ShardedCSB`` outside a model
+    mesh."""
+    from repro.dist.csb_partition import partition_padded
+    p, z = make_padded(rng, (48, 32), 16, 16, 0.5)
+    x = jnp.asarray(rng.normal(size=(3, 32)).astype(np.float32))
+    if weight == "sharded_no_mesh":
+        with pytest.raises(ValueError, match="model"):
+            ops.matvec(partition_padded(p, 2)[1], x)
+        return
+    w = jnp.asarray(z) if weight == "dense" else p
+    with jax.default_matmul_precision("highest"):
+        y = ops.matvec(w, x)
+    assert y.dtype == x.dtype and y.shape == (3, 48)
+    want = jnp.dot(x, jnp.asarray(z).T, precision=jax.lax.Precision.HIGHEST)
+    np.testing.assert_allclose(np.asarray(y), np.asarray(want),
+                               rtol=1e-5, atol=1e-5)

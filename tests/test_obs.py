@@ -307,22 +307,6 @@ def test_paged_serve_pool_gauges():
         res.stats["decode_steps"]
 
 
-def test_rnn_serve_frames_spans():
-    from repro.cells import init_params as cell_init, make_cell
-    from repro.serve import rnn_serve_frames
-    tr, reg = obs.enable_all()
-    cell = make_cell("lstm", 8, 16)
-    params = cell_init(cell, jax.random.PRNGKey(2))
-    frames = jax.random.normal(jax.random.PRNGKey(3), (5, 2, 8))
-    out = rnn_serve_frames(cell, params, frames, warmup=1,
-                           collect_frame_times=True)
-    assert len(out) == 4
-    frame_spans = [e for e in tr.events()
-                   if e[0] == "X" and e[1] == "serve/frame"]
-    assert len(frame_spans) == 5
-    assert reg.histogram("serve/frames/wall_us").count == 5
-
-
 FRAME_PHASES = ("prepare", "warmup", "dispatch", "sync", "stack")
 
 
@@ -333,7 +317,7 @@ def _serve_tiny_frames():
     params = cell_init(cell, jax.random.PRNGKey(2))
     frames = jax.random.normal(jax.random.PRNGKey(3), (5, 2, 8))
     for _ in range(2):
-        rnn_serve_frames(cell, params, frames, warmup=1)
+        rnn_serve_frames(cell, params, frames)
 
 
 def _profiled_frames(tmp_path) -> dict:

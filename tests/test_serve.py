@@ -62,7 +62,7 @@ def test_rnn_serve_frames_csb():
         else:
             csb_params[k] = w
     frames = jax.random.normal(jax.random.PRNGKey(6), (5, 2, 16))
-    outs, st, us = rnn_serve_frames(cell, csb_params, frames, warmup=1)
+    outs, st, us = rnn_serve_frames(cell, csb_params, frames)
     assert outs.shape == (5, 2, 32)
     assert np.isfinite(np.asarray(outs)).all()
     assert us > 0
@@ -96,7 +96,7 @@ def test_frame_step_cache_hit_matches_fresh_step(step_cache):
     cell, params = _dense_lstm()
     frames = jax.random.normal(jax.random.PRNGKey(3), (5, 2, 8))
     state = init_state(cell, (2,))
-    runs = [rnn_serve_frames(cell, params, frames, state, warmup=1)
+    runs = [rnn_serve_frames(cell, params, frames, state)
             for _ in range(2)]
     assert _lookups(step_cache) == (1, 1)
     # the same program as a step built fresh, bit for bit
@@ -122,7 +122,7 @@ def test_frame_step_cache_hit_matches_fresh_step(step_cache):
 def test_frame_step_cache_key(step_cache, monkeypatch, variant, want):
     cell, params = _dense_lstm()
     frames = jax.random.normal(jax.random.PRNGKey(3), (4, 2, 8))
-    rnn_serve_frames(cell, params, frames, warmup=1)
+    rnn_serve_frames(cell, params, frames)
     if variant == "same_structure":       # another object, equal graph
         cell = make_cell("lstm", 8, 16)
     elif variant == "other_structure":
@@ -133,7 +133,7 @@ def test_frame_step_cache_key(step_cache, monkeypatch, variant, want):
     else:
         real = engine.cell_apply
         monkeypatch.setattr(engine, "cell_apply", lambda *a: real(*a))
-    rnn_serve_frames(cell, params, frames, warmup=1)
+    rnn_serve_frames(cell, params, frames)
     assert _lookups(step_cache) == want
 
 
@@ -145,6 +145,26 @@ def test_frame_step_cache_keys_on_model_mesh(step_cache):
         sharded = engine._frame_step_for(cell)
         assert engine._frame_step_for(cell) is sharded
     assert sharded is not first
+    assert _lookups(step_cache) == (2, 1)
+
+
+def test_frame_step_and_rnnt_decode_share_the_trace_context(step_cache):
+    """Both program caches take their key from ``ops.trace_context()``:
+    entering a model mesh misses both, and inside it both hit."""
+    from repro.models import transducer as T
+    from repro.serve import transducer
+    model = T.make_transducer(6, 16, 8, encoder_layers=2, reduce_after=1,
+                              prediction_layers=1, vocab=5, embed_dim=4,
+                              joint_dim=8)
+    cell = model.encoder[0]
+    step, decode = (engine._frame_step_for(cell)[0],
+                    transducer._decode_program(model))
+    with use_rules(Rules({}, mesh=AbstractMesh((1, 2), ("data", "model")))):
+        step_in, decode_in = (engine._frame_step_for(cell)[0],
+                              transducer._decode_program(model))
+        assert step_in is not step and decode_in is not decode
+        assert engine._frame_step_for(cell)[0] is step_in
+        assert transducer._decode_program(model) is decode_in
     assert _lookups(step_cache) == (2, 1)
 
 
@@ -164,9 +184,9 @@ def test_frame_warmup_on_fresh_arguments_only(step_cache, monkeypatch):
     key = jax.random.PRNGKey(3)
     for batch in (2, 2, 3, 3, 2):
         rnn_serve_frames(cell, params,
-                         jax.random.normal(key, (4, batch, 8)), warmup=3)
-    # 3 warm-up steps the first time each batch size comes, then 4 frames
-    assert calls == [2] * 7 + [2] * 4 + [3] * 7 + [3] * 4 + [2] * 4
+                         jax.random.normal(key, (4, batch, 8)))
+    # one warm-up step the first time each batch size comes, then 4 frames
+    assert calls == [2] * 5 + [2] * 4 + [3] * 5 + [3] * 4 + [2] * 4
     assert _lookups(step_cache) == (1, 4)
 
 

@@ -48,7 +48,6 @@ def _requests(n=4, seed=3):
     (dict(cache_len=0), "cache_len"),
     (dict(n_slots=0), "n_slots"),
     (dict(page_size=0), "page_size"),
-    (dict(frame_warmup=-1), "frame_warmup"),
     (dict(use_kernel=True), "use_kernel=True requires paged=True"),
     (dict(prefix_cache=True), "prefix_cache=True requires paged=True"),
     (dict(pool_pages=8), "pool_pages requires paged=True"),

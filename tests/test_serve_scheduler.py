@@ -8,7 +8,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.sharding import Mesh
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.models import ModelConfig, decode_step, init_cache
 from repro.models import init_params as lm_init
@@ -232,7 +232,8 @@ def test_rnn_frames_sharded_matches_local(rng):
     from repro.core import (
         CSBSpec, csb_masks, csb_project, padded_csb_from_dense,
     )
-    from repro.serve import rnn_serve_frames
+    from repro.dist import Rules, use_rules
+    from repro.serve import rnn_serve_frames, shard_cell_params
 
     cell = make_cell("gru", 16, 32)
     wparams = cell_init(cell, jax.random.PRNGKey(8))
@@ -248,11 +249,13 @@ def test_rnn_frames_sharded_matches_local(rng):
         else:
             csb[k] = w
     frames = jnp.asarray(rng.normal(size=(4, 2, 16)).astype(np.float32))
-    outs, _, _ = rnn_serve_frames(cell, csb, frames, warmup=1)
+    outs, _, _ = rnn_serve_frames(cell, csb, frames)
     mesh = Mesh(np.asarray(jax.devices()[:8]).reshape(2, 4),
                 ("data", "model"))
-    outs_sh, _, us = rnn_serve_frames(cell, csb, frames, warmup=1,
-                                      mesh=mesh)
+    sharded = shard_cell_params(csb, mesh)        # once per model
+    frames = jax.device_put(frames, NamedSharding(mesh, P(None, "data")))
+    with use_rules(Rules({}, mesh=mesh)):
+        outs_sh, _, us = rnn_serve_frames(cell, sharded, frames)
     np.testing.assert_allclose(np.asarray(outs_sh), np.asarray(outs),
                                rtol=2e-5, atol=2e-5)
     assert us > 0
